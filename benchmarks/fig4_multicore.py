@@ -64,16 +64,14 @@ def main(smoke: bool = False) -> list[str]:
     n_items = s.m + s.n
 
     # bucketed engine (jit, jnp path)
-    sweep = jax.jit(s._sweep_impl)
-    t = time_fn(sweep, state, warmup=1, iters=1 if smoke else 3)
+    t = time_fn(s.sweep, state, warmup=1, iters=1 if smoke else 3)
     rows.append(csv_row("fig4_bucketed_updates_per_s", t * 1e6, f"{n_items / t:.0f}"))
 
     if not smoke:
         # kernel path (interpret mode — correctness, not speed)
         sk = GibbsSampler(train, None, k=k, alpha=1.5, widths="balanced",
                           use_kernel=True)
-        sweep_k = jax.jit(sk._sweep_impl)
-        t_k = time_fn(sweep_k, sk.init(0), warmup=1, iters=1)
+        t_k = time_fn(sk.sweep, sk.init(0), warmup=1, iters=1)
         rows.append(csv_row("fig4_kernel_interpret_updates_per_s", t_k * 1e6, f"{n_items / t_k:.0f}"))
 
         # naive python engine on a subsample (extrapolated)

@@ -25,6 +25,7 @@ SRC = str(Path(__file__).resolve().parents[1] / "src")
 _WORKER = """
 import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count={p}'
+os.environ['JAX_PLATFORMS'] = 'cpu'  # simulated host devices, never the chip
 import sys, json, time
 sys.path.insert(0, {src!r})
 import jax

@@ -30,6 +30,7 @@ SRC = str(Path(__file__).resolve().parents[1] / "src")
 _WORKER = """
 import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
+os.environ['JAX_PLATFORMS'] = 'cpu'  # simulated host devices, never the chip
 import sys, json, re
 sys.path.insert(0, {src!r})
 import jax
@@ -66,7 +67,7 @@ out = {{}}
 for mode in ("ring", "allgather", "async"):
     s = DistributedBPMF(train, test, k=32, alpha=1.5, mode=mode, width=32)
     st = s.init(0)
-    lowered = s._sweep.lower(st)
+    lowered = s._sweep.lower(st, *s._plan_args)
     txt = lowered.compile().as_text()
     res = HloCostModel(txt).analyze()
     out[mode] = {{

@@ -38,6 +38,7 @@ def main() -> list[str]:
     code = f"""
     import os
     os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
+    os.environ['JAX_PLATFORMS'] = 'cpu'  # simulated host devices, never the chip
     import sys, json
     sys.path.insert(0, {SRC!r})
     from repro.data import chembl_like, train_test_split

@@ -153,9 +153,9 @@ def _flat_study(*, m, n, base_nnz, minibatch, iters):
         )
         s = SGLDSampler(ratings, None, k=16, alpha=ALPHA,
                         minibatch=minibatch)
-        t_s = time_fn(s._sweep, s.init(0), warmup=1, iters=iters)
+        t_s = time_fn(s.sweep, s.init(0), warmup=1, iters=iters)
         g = GibbsSampler(ratings, None, k=16, alpha=ALPHA, engine="fused")
-        t_g = time_fn(g._sweep, g.init(0), warmup=1, iters=iters)
+        t_g = time_fn(g.sweep, g.init(0), warmup=1, iters=iters)
         steps[mult] = (t_s, t_g)
         rows.append(csv_row(
             f"rw_flat_{mult}x", t_s * 1e6,

@@ -51,7 +51,7 @@ TARGET_SPEEDUP = 1.5   # acceptance floor: restructured/fused vs reference
 def measure_engine(train, widths, engine, k, iters):
     s = GibbsSampler(train, None, k=k, alpha=1.5, widths=widths, engine=engine)
     state = s.init(0)
-    sweep = s._sweep          # the sampler's own jitted sweep (run() path)
+    sweep = s.sweep           # the sampler's own jitted sweep (run() path)
     t = time_fn(sweep, state, warmup=1, iters=iters)
     n_updates = s.m + s.n
     out = sweep(state)

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.gibbs import chol_subst_solve
+from repro.core.gibbs import chol_subst_solve, chunk_rows
 from repro.core.hyper import (
     HyperParams,
     NWPrior,
@@ -55,11 +55,6 @@ from repro.core.partition import GridPlan, build_grid_plan, partition_entities
 from repro.data.sparse import SparseRatings
 
 AXIS = "items"
-
-
-# jax.shard_map shim (check_vma vs check_rep across jax versions) — shared
-# with models/layers.py and the distributed tests
-from repro.compat import shard_map as _shard_map
 
 
 class DistState(NamedTuple):
@@ -100,38 +95,32 @@ def _per_item_noise(key: jax.Array, item_ids: jax.Array, k: int) -> jax.Array:
     return jax.vmap(lambda kk: jax.random.normal(kk, (k,), jnp.float32))(keys)
 
 
-def _accumulate_block(counter_blk, idx, val, msk, seg, seg_dense, seg_map,
-                      n_loc, *, engine="einsum"):
-    """Partial (prec, rhs) of local items against one counterpart block.
+def _accumulate_block(prec, rhs, counter_blk, idx, val, msk, seg, seg_dense,
+                      seg_map, *, engine="einsum"):
+    """Add the statistics of local items against one counterpart block into
+    the (n_loc, K, K) / (n_loc, K) accumulators.
 
-    einsum: gathered block + row-level einsums + segment_sum (the
-    equivalence-tested reference). fused: `ops.gather_syrk_seg` — the
-    counterpart block is gathered in-kernel against the dense per-block
-    segment ids and the per-segment outputs scatter once through seg_map
-    (slot n_loc collects the padding and is dropped).
+    einsum: gathered block + row-level einsums, scatter-added by row
+    segment (the equivalence-tested reference). fused: `ops.gather_syrk_seg`
+    — the counterpart block is gathered in-kernel against the dense
+    per-block segment ids and the per-segment outputs scatter once through
+    seg_map. Slot n_loc collects the padding and is dropped. Adding into the
+    accumulators in place keeps one (n_loc, K, K) buffer live, not two.
     """
     if engine == "fused":
         from repro.kernels import ops as kops
 
-        r = idx.shape[0]
-        k = counter_blk.shape[-1]
         prec_seg, rhs_seg = kops.gather_syrk_seg(
-            idx, val, msk, seg_dense, r, counter_blk
+            idx, val, msk, seg_dense, idx.shape[0], counter_blk
         )
-        prec = jnp.zeros((n_loc + 1, k, k), jnp.float32).at[seg_map].add(
-            prec_seg
-        )[:n_loc]
-        rhs = jnp.zeros((n_loc + 1, k), jnp.float32).at[seg_map].add(
-            rhs_seg
-        )[:n_loc]
-        return prec, rhs
+        return (prec.at[seg_map].add(prec_seg, mode="drop"),
+                rhs.at[seg_map].add(rhs_seg, mode="drop"))
     vg = counter_blk[idx]                            # (R, W, K)
     vm = vg * msk[..., None]
     prec_rows = jnp.einsum("rwk,rwl->rkl", vm, vm, preferred_element_type=jnp.float32)
     rhs_rows = jnp.einsum("rwk,rw->rk", vm, val * msk)
-    prec = jax.ops.segment_sum(prec_rows, seg, n_loc + 1)[:n_loc]
-    rhs = jax.ops.segment_sum(rhs_rows, seg, n_loc + 1)[:n_loc]
-    return prec, rhs
+    return (prec.at[seg].add(prec_rows, mode="drop"),
+            rhs.at[seg].add(rhs_rows, mode="drop"))
 
 
 def _phase_ring(key, counter_blk, plans, item_ids, hyper, alpha, n_shards,
@@ -151,15 +140,15 @@ def _phase_ring(key, counter_blk, plans, item_ids, hyper, alpha, n_shards,
         blk, prec, rhs = carry
         src = jnp.mod(pid - s, n_shards)
         take = lambda a: jnp.take(a, src, axis=0)
-        dp, dr = _accumulate_block(
-            blk, take(idx_all), take(val_all), take(msk_all), take(seg_all),
-            take(segd_all), take(segm_all), n_loc, engine=engine,
+        prec, rhs = _accumulate_block(
+            prec, rhs, blk, take(idx_all), take(val_all), take(msk_all),
+            take(seg_all), take(segd_all), take(segm_all), engine=engine,
         )
         # forward the block; independent of this step's accumulate -> overlap
         blk = jax.lax.ppermute(
             blk, AXIS, [(i, (i + 1) % n_shards) for i in range(n_shards)]
         )
-        return (blk, prec + dp, rhs + dr), None
+        return (blk, prec, rhs), None
 
     prec0 = jnp.zeros((n_loc, k, k), jnp.float32)
     rhs0 = jnp.zeros((n_loc, k), jnp.float32)
@@ -170,12 +159,25 @@ def _phase_ring(key, counter_blk, plans, item_ids, hyper, alpha, n_shards,
 
 
 def _finish_phase(key, prec, rhs, item_ids, hyper, alpha):
-    """Raw accumulated stats -> posterior draw for this shard's items."""
-    k = rhs.shape[-1]
-    prec = hyper.lam[None] + alpha * prec
-    rhs = (hyper.lam @ hyper.mu)[None] + alpha * rhs
+    """Raw accumulated stats -> posterior draw for this shard's items.
+
+    Solved in fixed-size item chunks (the last one clamped to end at n_loc,
+    re-solving a few items identically) so the Cholesky factors of a full
+    ChEMBL shard never coexist with its statistics.
+    """
+    n, k = rhs.shape
+    c = min(n, chunk_rows(k, k))
     z = _per_item_noise(key, item_ids, k)
-    new = _chol_sample(prec, rhs, z)
+    lam, lam_mu = hyper.lam[None], (hyper.lam @ hyper.mu)[None]
+
+    def solve_chunk(i, new):
+        start = jnp.minimum(i * c, n - c)
+        part = lambda a: jax.lax.dynamic_slice_in_dim(a, start, c)
+        x = _chol_sample(lam + alpha * part(prec), lam_mu + alpha * part(rhs),
+                         part(z))
+        return jax.lax.dynamic_update_slice_in_dim(new, x, start, 0)
+
+    new = jax.lax.fori_loop(0, -(-n // c), solve_chunk, jnp.zeros_like(rhs))
     return jnp.where(item_ids[:, None] >= 0, new, 0.0)
 
 
@@ -208,9 +210,9 @@ def _phase_ring_async(k_v, k_u, u_blk, v_blk, v_plans, u_plans, v_ids, u_ids,
         # next blocks, issued before either accumulate touches the held ones
         ub_next = jax.lax.ppermute(ub, AXIS, fwd)
         vb_next = jax.lax.ppermute(vb, AXIS, fwd)
-        dpv, drv = _accumulate_block(ub, *take(v_plans), n_v, engine=engine)
-        dpu, dru = _accumulate_block(vb, *take(u_plans), n_u, engine=engine)
-        return (ub_next, vb_next, pv + dpv, rv + drv, pu + dpu, ru + dru), None
+        pv, rv = _accumulate_block(pv, rv, ub, *take(v_plans), engine=engine)
+        pu, ru = _accumulate_block(pu, ru, vb, *take(u_plans), engine=engine)
+        return (ub_next, vb_next, pv, rv, pu, ru), None
 
     init = (
         u_blk, v_blk,
@@ -228,10 +230,11 @@ def _phase_allgather(key, counter_blk, plan_full, item_ids, hyper, alpha,
     """Sync baseline: gather the whole counterpart, then sweep locally."""
     full = jax.lax.all_gather(counter_blk, AXIS)      # (P, n_loc, K)
     full = full.reshape(-1, full.shape[-1])
-    idx, val, msk, seg, seg_dense, seg_map = plan_full
     n_loc = item_ids.shape[0]
+    k = full.shape[-1]
     prec, rhs = _accumulate_block(
-        full, idx, val, msk, seg, seg_dense, seg_map, n_loc, engine=engine
+        jnp.zeros((n_loc, k, k), jnp.float32), jnp.zeros((n_loc, k), jnp.float32),
+        full, *plan_full, engine=engine,
     )
     return _finish_phase(key, prec, rhs, item_ids, hyper, alpha)
 
@@ -323,7 +326,7 @@ def make_sweep(mesh: Mesh, mode: str, alpha: float, prior: NWPrior,
         v_eval=P(AXIS) if mode == "async" else None,
     )
     plans_in = tuple(P(AXIS) for _ in range(6))
-    return _shard_map(
+    return jax.shard_map(
         sweep,
         mesh=mesh,
         in_specs=(state_spec, plans_in, plans_in, P(AXIS), P(AXIS)),
@@ -441,11 +444,9 @@ class DistributedBPMF:
         u_plans = self.u_flat if self.mode == "allgather" else self.u_ring
         v_plans = self.v_flat if self.mode == "allgather" else self.v_ring
 
-        @jax.jit
-        def run(state):
-            return mapped(state, u_plans, v_plans, self.u_ids, self.v_ids)
-
-        return run
+        # plans ride in as arguments, not as constants baked into the program
+        self._plan_args = (u_plans, v_plans, self.u_ids, self.v_ids)
+        return jax.jit(mapped)
 
     # ------------------------------------------------------------------
     def init(self, seed: int = 0) -> DistState:
@@ -472,7 +473,7 @@ class DistributedBPMF:
         )
 
     def sweep(self, state: DistState) -> DistState:
-        return self._sweep(state)
+        return self._sweep(state, *self._plan_args)
 
     def gather_factors(self, state: DistState, *, coupled: bool = True):
         """(M, K), (N, K) in global entity order (host-side, for eval).

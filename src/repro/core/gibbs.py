@@ -104,6 +104,16 @@ class DeviceBucket(NamedTuple):
     identity_segments: bool = False
 
 
+# A pytree whose leaves are the arrays only: the sizes and the identity flag
+# are static, so a plan passed into jit as an argument keeps static shapes.
+jax.tree_util.register_pytree_node(
+    DeviceBucket,
+    lambda b: ((b.indices, b.values, b.mask, b.seg_ids, b.seg_item_ids),
+               (b.width, b.n_segments, b.identity_segments)),
+    lambda aux, arrs: DeviceBucket(aux[0], *arrs[:4], aux[1], arrs[4], aux[2]),
+)
+
+
 def device_plan(
     plan: BucketPlan | Sequence[Bucket],
 ) -> tuple[DeviceBucket, ...]:
@@ -313,6 +323,77 @@ def sample_mvn_precision(
     return (mean + noise)[..., 0]
 
 
+# Largest per-chunk intermediate of a bucket update, in bytes. A full-size
+# ChEMBL user bucket holds 150k rows: its (rows, K, K) statistics and their
+# Cholesky factors are 2.5 GB each at K=64, so big buckets are swept in row
+# chunks (a sequential lax.map / scan) to keep the sweep inside one chip's HBM.
+CHUNK_BYTES = 1 << 28
+
+
+def chunk_rows(width: int, k: int) -> int:
+    """Power-of-two rows per chunk: gathered (C, W, K) and (C, K, K) stay
+    under CHUNK_BYTES each."""
+    per_row = 4 * k * max(width, k)
+    return max(8, 1 << int(np.log2(max(CHUNK_BYTES // per_row, 1))))
+
+
+def _bucket_update(counterpart, b: DeviceBucket, sample, *, engine,
+                   bf16_gather):
+    """Posterior draws (n_segments, K) for one bucket's items.
+
+    `sample(prec, rhs, item_ids)` turns per-segment statistics into draws.
+    A bucket longer than one chunk is swept chunk by chunk: identity-segment
+    buckets statistics-and-sample each chunk of rows; a bucket that splits
+    items across rows accumulates its per-segment statistics over the chunks,
+    then samples once.
+    """
+    k = counterpart.shape[-1]
+    rows = b.indices.shape[0]
+    c = chunk_rows(b.width, k)
+
+    def stats(bucket):
+        return bucket_stats(counterpart, bucket, engine=engine,
+                            bf16_gather=bf16_gather)
+
+    if rows <= c:
+        return sample(*stats(b), b.seg_item_ids)
+    n_chunks = -(-rows // c)
+    pad = n_chunks * c - rows
+
+    def chunked(x, mode="constant"):
+        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1), mode=mode)
+        return x.reshape((n_chunks, c) + x.shape[1:])
+
+    # pad rows carry mask 0; seg_ids repeat the last segment (sorted)
+    parts = (chunked(b.indices), chunked(b.values), chunked(b.mask),
+             chunked(b.seg_ids, "edge"))
+
+    def chunk_bucket(idx, val, msk, seg, n_segments, identity):
+        return DeviceBucket(b.width, idx, val, msk, seg, n_segments,
+                            b.seg_item_ids, identity)
+
+    if b.identity_segments:
+        ids = chunked(b.seg_item_ids)
+
+        def one(args):
+            *arrs, item_ids = args
+            local = jnp.arange(c, dtype=jnp.int32)
+            return sample(*stats(chunk_bucket(*arrs[:3], local, c, True)),
+                          item_ids)
+
+        x = jax.lax.map(one, (*parts[:3], ids))
+        return x.reshape(n_chunks * c, k)[:rows]
+
+    def acc(carry, arrs):
+        p, r = stats(chunk_bucket(*arrs, b.n_segments, False))
+        return (carry[0] + p, carry[1] + r), None
+
+    init = (jnp.zeros((b.n_segments, k, k), jnp.float32),
+            jnp.zeros((b.n_segments, k), jnp.float32))
+    (prec, rhs), _ = jax.lax.scan(acc, init, parts)
+    return sample(prec, rhs, b.seg_item_ids)
+
+
 def update_factors(
     key: jax.Array,
     counterpart: jax.Array,
@@ -330,13 +411,13 @@ def update_factors(
     Also returns the sufficient statistics of the *new* factor matrix (fused
     aggregation, paper Sec 3.1).
 
-    The restructured flow (every engine except "reference") writes each
-    bucket's per-segment statistics straight into their seg_item_ids slots:
-    the per-item buffers start as the broadcast hyper-prior and receive ONE
-    scatter-add of the concatenated per-segment outputs — the bucket plan
-    partitions items, so indices are unique and items with no ratings keep
-    the prior, exactly as in the seed flow. The seed flow's per-bucket
-    full-size zero buffers and double scatter passes are gone.
+    The restructured flow (every engine except "reference") solves bucket by
+    bucket: each bucket's per-segment statistics get the hyper-prior added
+    and are sampled with the noise rows of their items, and the draws are
+    written into their seg_item_ids rows of a prior draw for every item —
+    the bucket plan partitions items, so indices are unique and items with
+    no ratings keep the prior draw, as in the seed flow. No (n_items, K, K)
+    buffer exists: a full-size user side would need 7.9 GB for it at K=64.
     """
     engine = resolve_engine(engine, use_kernel)
     k = counterpart.shape[-1]
@@ -353,23 +434,26 @@ def update_factors(
         rhs_all = (hyper.lam @ hyper.mu)[None] + alpha * rhs_all
         new = sample_mvn_precision(key, prec_all, rhs_all, solver="lapack")
     else:
-        seg = [
-            bucket_stats(counterpart, b, engine=engine, bf16_gather=bf16_gather)
-            for b in buckets
-        ]
-        ids = jnp.concatenate([b.seg_item_ids for b in buckets])
-        prec_cat = jnp.concatenate([p for p, _ in seg])
-        rhs_cat = jnp.concatenate([r for _, r in seg])
-        prec_all = jnp.broadcast_to(hyper.lam, (n_items, k, k)).astype(dtype)
-        rhs_all = jnp.broadcast_to(hyper.lam @ hyper.mu, (n_items, k)).astype(dtype)
-        prec_all = prec_all.at[ids].add(
-            (alpha * prec_cat).astype(dtype), unique_indices=True
-        )
-        rhs_all = rhs_all.at[ids].add(
-            (alpha * rhs_cat).astype(dtype), unique_indices=True
+        lam = hyper.lam.astype(dtype)
+        lam_mu = (hyper.lam @ hyper.mu).astype(dtype)
+        z = jax.random.normal(key, (n_items, k), dtype)
+        # items with no ratings keep the prior: one shared Cholesky factor
+        new = chol_subst_solve(
+            jnp.linalg.cholesky(lam), jnp.broadcast_to(lam_mu, (n_items, k)), z
         )
         solver = "kernel" if engine == "kernel" else "subst"
-        new = sample_mvn_precision(key, prec_all, rhs_all, solver=solver)
+
+        def sample(prec, rhs, item_ids):
+            prec = lam + (alpha * prec).astype(dtype)
+            rhs = lam_mu + (alpha * rhs).astype(dtype)
+            return sample_mvn_precision(
+                None, prec, rhs, z=z[item_ids], solver=solver
+            )
+
+        for b in buckets:
+            x = _bucket_update(counterpart, b, sample, engine=engine,
+                               bf16_gather=bf16_gather)
+            new = new.at[b.seg_item_ids].set(x, unique_indices=True)
 
     stats = FactorStats(
         sum_x=new.sum(0),
@@ -455,6 +539,9 @@ class GibbsSampler:
             self.test_vals = jnp.zeros((0,), jnp.float32)
 
         self.prior = default_prior(k, dtype)
+        # the plans ride in as arguments: closed over, they would be baked
+        # into the program as constants (tens of MB at full ChEMBL size)
+        self._plan_args = ((self.item_buckets, self.user_buckets),)
         self._sweep = jax.jit(self._sweep_impl)
 
     def init(self, seed: int = 0) -> BPMFState:
@@ -472,14 +559,15 @@ class GibbsSampler:
         )
 
     # --- one full Gibbs sweep (Algorithm 1 body) ---
-    def _sweep_impl(self, state: BPMFState) -> BPMFState:
+    def _sweep_impl(self, state: BPMFState, plans) -> BPMFState:
+        item_buckets, user_buckets = plans
         key, k_hv, k_v, k_hu, k_u = jax.random.split(state.key, 5)
 
         # Movies phase: hyper from V stats, then update V given U.
         sv = factor_stats(state.v)
         hyper_v = sample_normal_wishart(k_hv, sv.sum_x, sv.sum_xxt, sv.n, self.prior)
         v_new, _ = update_factors(
-            k_v, state.u, self.item_buckets, self.n, hyper_v, self.alpha,
+            k_v, state.u, item_buckets, self.n, hyper_v, self.alpha,
             engine=self.engine, bf16_gather=self.bf16_gather,
         )
 
@@ -487,7 +575,7 @@ class GibbsSampler:
         su = factor_stats(state.u)
         hyper_u = sample_normal_wishart(k_hu, su.sum_x, su.sum_xxt, su.n, self.prior)
         u_new, _ = update_factors(
-            k_u, v_new, self.user_buckets, self.m, hyper_u, self.alpha,
+            k_u, v_new, user_buckets, self.m, hyper_u, self.alpha,
             engine=self.engine, bf16_gather=self.bf16_gather,
         )
 
@@ -512,7 +600,7 @@ class GibbsSampler:
         )
 
     def sweep(self, state: BPMFState) -> BPMFState:
-        return self._sweep(state)
+        return self._sweep(state, *self._plan_args)
 
     def rmse(self, state: BPMFState) -> float:
         """Posterior-mean RMSE over the test set (paper's accuracy metric)."""

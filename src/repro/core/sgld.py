@@ -53,7 +53,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map as _shard_map
 from repro.core.distributed import (
     AXIS,
     DIST_MODES,
@@ -302,7 +301,8 @@ class SGLDSampler(GibbsSampler):
         return precond_gain(degrees, self.alpha, _lam_bar(hyper), sig2)
 
     # --- one SGLD step (two preconditioned Langevin half-steps) ---
-    def _sweep_impl(self, state):
+    def _sweep_impl(self, state, plans):
+        item_buckets, user_buckets = plans
         key, k_hv, k_hu, k_sv, k_su, k_nv, k_nu = jax.random.split(state.key, 7)
         eps = sgld_step_schedule(
             state.step, peak=self.step_size, decay=self.step_decay,
@@ -331,7 +331,7 @@ class SGLDSampler(GibbsSampler):
 
         # movies half-step: minibatch gradient of V given U
         g_lik = minibatch_likelihood_grad(
-            k_sv, state.v, state.u, self.item_buckets,
+            k_sv, state.v, state.u, item_buckets,
             self.item_rows, self.item_scales,
         )
         grad_v = self.alpha * g_lik - (state.v - hyper_v.mu) @ hyper_v.lam
@@ -343,7 +343,7 @@ class SGLDSampler(GibbsSampler):
 
         # users half-step: minibatch gradient of U given the new V
         g_lik = minibatch_likelihood_grad(
-            k_su, state.u, v_new, self.user_buckets,
+            k_su, state.u, v_new, user_buckets,
             self.user_rows, self.user_scales,
         )
         grad_u = self.alpha * g_lik - (state.u - hyper_u.mu) @ hyper_u.lam
@@ -616,7 +616,7 @@ def make_sgld_sweep(mesh: Mesh, mode: str, alpha: float, prior: NWPrior,
         v_eval=P(AXIS) if mode == "async" else None,
     )
     plans_in = tuple(P(AXIS) for _ in range(6))
-    return _shard_map(
+    return jax.shard_map(
         sweep,
         mesh=mesh,
         in_specs=(state_spec, plans_in, plans_in, P(AXIS), P(AXIS),
@@ -723,9 +723,6 @@ class DistributedSGLD(DistributedBPMF):
         u_plans = self.u_flat if self.mode == "allgather" else self.u_ring
         v_plans = self.v_flat if self.mode == "allgather" else self.v_ring
 
-        @jax.jit
-        def run(state):
-            return mapped(state, u_plans, v_plans, self.u_ids, self.v_ids,
-                          self.u_deg, self.v_deg)
-
-        return run
+        self._plan_args = (u_plans, v_plans, self.u_ids, self.v_ids,
+                           self.u_deg, self.v_deg)
+        return jax.jit(mapped)

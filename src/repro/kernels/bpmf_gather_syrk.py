@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: FUSED gather + masked syrk + segment reduce for BPMF.
+"""Pallas TPU kernel: FUSED gather + masked syrk for BPMF.
 
 The training sweep's hot loop is, per bucket row r with counterpart ids
 idx[r, :] and ratings val[r, :]:
@@ -8,36 +8,28 @@ idx[r, :] and ratings val[r, :]:
 
 followed by a per-item segment reduction over rows (long-tail items are
 split across rows). The two-step path (`bpmf_syrk.py`) makes the gathered
-(R, W, K) factor block round-trip through HBM (gather write + kernel read)
-and then materializes the row-level (R, K, K) precision intermediate for a
-separate `segment_sum` — on the BPMF roofline those two are the dominant
-memory terms. This kernel eliminates both:
+(R, W, K) factor block round-trip through HBM (gather write + kernel read).
+This kernel gathers the rows itself:
 
   * V stays in HBM/ANY space; rows are gathered *inside* the kernel with
-    double-buffered per-row DMA into a (2, BR, BW, K) VMEM scratch — the
+    double-buffered per-row DMA into a (2, BR, BW, L) VMEM scratch — the
     W axis is tiled, and tile t+1's row DMAs are issued before tile t is
-    consumed, so the gather streams HBM exactly once.
-  * The masked outer-product sum runs on the MXU (`dot_general` over the
-    W tile) into fp32 accumulators. With a bf16 V the caller passes the
-    factor matrix pre-cast (one cast amortized over every gathered row
-    read) and only the accumulation is fp32 — halving the gather traffic.
-  * Segment reduction happens *in kernel*: bucket rows are ordered by
-    segment (nondecreasing, dense 0..n_segments-1 — the planner invariant),
-    so the rows of one grid step span at most `block_rows` consecutive
-    segments. A one-hot (BR, BR) matmul collapses the row block to
-    per-segment partials which are accumulated into the output range
-    [seg0, seg0 + BR) — per-segment (prec, rhs) exit the kernel directly
-    and the (R, K, K) row-level intermediate never exists.
+    consumed, so the gather streams HBM exactly once. Mosaic slices an
+    HBM row only at full lane tiles, so the wrapper pads V's K axis to a
+    multiple of 128 lanes (L); the kernel computes on the first K lanes.
+    The row indices arrive through SMEM, where scalar reads are legal.
+  * Per row, the masked outer-product sum is one 2-D MXU product
+    (BW, K)^T (BW, K) with fp32 accumulation. With ``bf16`` the gathered
+    rows are rounded to bf16 before the product (fp32 accumulate); the
+    gather itself stays fp32, because a bf16 HBM row is not a whole tile.
+  * Row-level statistics leave through VMEM output blocks, one (BR, K, K)
+    block per grid step. The segment reduction is XLA's sorted
+    `segment_sum` in the wrapper (`kernels.ops.gather_syrk_seg`), skipped
+    for the common identity-segment bucket where it is a no-op.
 
-A leading stacked-draw axis (V of shape (S, N, K), e.g. the serving
+A leading stacked-draw axis (V of shape (S, N, L), e.g. the serving
 fold-in's S retained draws) becomes the slow grid dimension: the same plan
 block is swept against every draw's factors.
-
-The accumulating output writes rely on the TPU grid being sequential
-(default dimension semantics — no "parallel" annotation); outputs are
-zero-initialized through `input_output_aliases`. Validated in interpret
-mode against the einsum reference; on real hardware the ANY-space
-load/store pair on the output range lowers to a VMEM round trip per block.
 """
 from __future__ import annotations
 
@@ -48,22 +40,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
 
-def _gather_syrk_seg_kernel(
-    seg_ref,                      # scalar prefetch: (R,) int32, nondecreasing
-    idx_ref, val_ref, msk_ref,    # (BR, W) VMEM row blocks
-    v_ref,                        # ANY: (N, K) or (S, N, K) — gathered in-kernel
-    pz_ref, rz_ref,               # zero inits, aliased onto the outputs
-    prec_ref, rhs_ref,            # ANY outputs: (..., P, K, K), (..., P, K)
-    gather_buf,                   # VMEM scratch: (2, BR, BW, K)
+
+def _gather_syrk_kernel(
+    idx_ref,                      # SMEM (BR, W) int32 row block
+    val_ref, msk_ref,             # VMEM (BR, W) row blocks
+    v_ref,                        # ANY: (N, L) or (S, N, L) — gathered in-kernel
+    prec_ref, rhs_ref,            # VMEM out blocks: (BR, K, K), (BR, K)
+    gather_buf,                   # VMEM scratch: (2, BR, BW, L)
     dma_sem,                      # DMA semaphores: (2,)
-    *, width: int, block_w: int, block_rows: int, stacked: bool,
+    *, width: int, block_w: int, k: int, stacked: bool, bf16: bool,
 ):
-    del pz_ref, rz_ref  # aliased zero-init buffers; written via prec/rhs refs
-    i = pl.program_id(1) if stacked else pl.program_id(0)
     s = pl.program_id(0) if stacked else None
-    br = idx_ref.shape[0]
-    k = v_ref.shape[-1]
+    br = val_ref.shape[0]
     n_wt = width // block_w
 
     def row_dma(slot, wt, t):
@@ -71,148 +61,118 @@ def _gather_syrk_seg_kernel(
         r = t // block_w
         w = t % block_w
         j = idx_ref[r, wt * block_w + w]
-        src = (v_ref.at[s, pl.dslice(j, 1), :] if stacked
-               else v_ref.at[pl.dslice(j, 1), :])
+        src = (v_ref.at[s, pl.ds(j, 1), :] if stacked
+               else v_ref.at[pl.ds(j, 1), :])
         return pltpu.make_async_copy(
-            src, gather_buf.at[slot, r, pl.dslice(w, 1), :], dma_sem.at[slot]
+            src, gather_buf.at[slot, r, pl.ds(w, 1), :], dma_sem.at[slot]
         )
 
     def tile_start(slot, wt):
         jax.lax.fori_loop(
-            0, br * block_w, lambda t, _: (row_dma(slot, wt, t).start(), 0)[1], 0
+            0, br * block_w, lambda t, c: (row_dma(slot, wt, t).start(), c)[1], 0
         )
 
     def tile_wait(slot, wt):
         jax.lax.fori_loop(
-            0, br * block_w, lambda t, _: (row_dma(slot, wt, t).wait(), 0)[1], 0
+            0, br * block_w, lambda t, c: (row_dma(slot, wt, t).wait(), c)[1], 0
         )
 
     # double-buffered W tiles: issue tile t+1's row DMAs before consuming t
     tile_start(0, 0)
-    acc_p = jnp.zeros((br, k, k), jnp.float32)
-    acc_r = jnp.zeros((br, k), jnp.float32)
+    acc_p = [jnp.zeros((k, k), jnp.float32) for _ in range(br)]
+    acc_r = [jnp.zeros((1, k), jnp.float32) for _ in range(br)]
     for wt in range(n_wt):  # static unroll: width // block_w is small
         if wt + 1 < n_wt:
             tile_start((wt + 1) % 2, wt + 1)
         tile_wait(wt % 2, wt)
-        g = gather_buf[wt % 2]                                 # (BR, BW, K)
-        m = msk_ref[:, wt * block_w:(wt + 1) * block_w]        # (BR, BW)
-        vv = val_ref[:, wt * block_w:(wt + 1) * block_w]
-        gm = g * m[..., None].astype(g.dtype)
-        # fp32 accumulation over a possibly-bf16 gathered block (MXU shapes)
-        acc_p += jax.lax.dot_general(
-            gm, g, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        acc_r += jax.lax.dot_general(
-            (vv * m)[:, None, :], gm.astype(jnp.float32),
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )[:, 0, :]
-
-    # in-kernel segment reduction: rows are segment-sorted and dense, so this
-    # block's segments span [seg0, seg0 + BR); collapse with a one-hot matmul
-    seg_blk = seg_ref[pl.dslice(i * block_rows, block_rows)]
-    seg0 = seg_blk[0]
-    local = seg_blk - seg0                                     # (BR,) in [0, BR)
-    onehot = (
-        local[None, :] == jax.lax.broadcasted_iota(jnp.int32, (br, br), 0)
-    ).astype(jnp.float32)
-    part_p = jax.lax.dot_general(
-        onehot, acc_p.reshape(br, k * k), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(br, k, k)
-    part_r = jax.lax.dot_general(
-        onehot, acc_r, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    # accumulate into the owned/overlapping output range (sequential grid)
-    if stacked:
-        pidx = (s, pl.dslice(seg0, br), slice(None), slice(None))
-        ridx = (s, pl.dslice(seg0, br), slice(None))
-    else:
-        pidx = (pl.dslice(seg0, br), slice(None), slice(None))
-        ridx = (pl.dslice(seg0, br), slice(None))
-    # the ANY-space ranged read-modify-write is this kernel's documented
-    # Mosaic hazard (module docstring + ROADMAP "TPU hardware verification"
-    # item): correct under the sequential grid in interpret mode, pending a
-    # hardware check / alternative accumulation layout on real TPUs.
-    pl.store(prec_ref, pidx, pl.load(prec_ref, pidx) + part_p)  # repro-lint: disable=pallas-anyspace
-    pl.store(rhs_ref, ridx, pl.load(rhs_ref, ridx) + part_r)  # repro-lint: disable=pallas-anyspace
+        cols = slice(wt * block_w, (wt + 1) * block_w)
+        m = msk_ref[:, cols]                                   # (BR, BW)
+        rv = val_ref[:, cols] * m
+        m_t = m.T                                              # (BW, BR)
+        for r in range(br):  # one 2-D MXU product per row of the block
+            g = gather_buf[wt % 2, r][:, :k]                   # (BW, K) f32
+            if bf16:
+                g = g.astype(jnp.bfloat16)
+            gm = g * m_t[:, r:r + 1].astype(g.dtype)
+            acc_p[r] += jax.lax.dot_general(
+                gm, g, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            acc_r[r] += jax.lax.dot_general(
+                rv[r:r + 1], g.astype(jnp.float32), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+    for r in range(br):
+        prec_ref[r] = acc_p[r]
+        rhs_ref[r:r + 1, :] = acc_r[r]
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("n_seg_padded", "block_rows", "block_w", "interpret"),
+    jax.jit, static_argnames=("k", "block_rows", "block_w", "bf16", "interpret"),
 )
-def gather_syrk_seg_pallas(
+def gather_syrk_pallas(
     indices: jax.Array,   # (R, W) int32 — rows of v to gather
     values: jax.Array,    # (R, W) f32
     mask: jax.Array,      # (R, W) f32 (0/1)
-    seg_ids: jax.Array,   # (R,) int32 — nondecreasing dense segment per row
-    v: jax.Array,         # (N, K) or (S, N, K); f32 or bf16 (bf16-gather mode)
+    v: jax.Array,         # (N, L) or (S, N, L) f32, L a multiple of 128
     *,
-    n_seg_padded: int,    # >= max(seg_ids) + block_rows, tile-aligned
+    k: int,               # the real factor rank: v[..., :k] is used
     block_rows: int = 8,
     block_w: int = 128,
+    bf16: bool = False,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Fused gather→syrk→segment-reduce. Returns per-SEGMENT statistics
+    """Fused gather→syrk. Returns per-ROW statistics
 
-        prec (..., n_seg_padded, K, K), rhs (..., n_seg_padded, K)
+        prec (..., R, K, K), rhs (..., R, K)
 
-    with a leading draw axis iff ``v`` carried one. Rows must arrive
-    segment-sorted (callers: `kernels.ops.gather_syrk_seg` pads + checks).
+    with a leading draw axis iff ``v`` carried one. Callers pad and reduce
+    through `kernels.ops.gather_syrk_seg`.
     """
     r, w = indices.shape
     stacked = v.ndim == 3
-    k = v.shape[-1]
+    lanes = v.shape[-1]
     assert r % block_rows == 0 and w % block_w == 0, (r, w, block_rows, block_w)
+    assert lanes % LANES == 0 and k <= lanes, (lanes, k)
     kernel = functools.partial(
-        _gather_syrk_seg_kernel, width=w, block_w=block_w,
-        block_rows=block_rows, stacked=stacked,
+        _gather_syrk_kernel, width=w, block_w=block_w, k=k,
+        stacked=stacked, bf16=bf16,
     )
-    grid = (v.shape[0], r // block_rows) if stacked else (r // block_rows,)
-    lead = (v.shape[0],) if stacked else ()
-
-    # index maps receive (*grid_indices, seg_prefetch_ref); the row-block
-    # index is always the fastest-varying grid axis
-    def row_block(*args):
-        *ids, _seg = args
-        i = ids[-1]
-        return (i, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, w), row_block),
-            pl.BlockSpec((block_rows, w), row_block),
-            pl.BlockSpec((block_rows, w), row_block),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # v: gathered in-kernel
-            pl.BlockSpec(memory_space=pltpu.ANY),   # zero init (aliased)
-            pl.BlockSpec(memory_space=pltpu.ANY),   # zero init (aliased)
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, block_rows, block_w, k), v.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    pz = jnp.zeros(lead + (n_seg_padded, k, k), jnp.float32)
-    rz = jnp.zeros(lead + (n_seg_padded, k), jnp.float32)
+    n_blocks = r // block_rows
+    if stacked:
+        n_draws = v.shape[0]
+        grid = (n_draws, n_blocks)
+        row_block = lambda s, i: (i, 0)  # noqa: E731
+        out_specs = [
+            pl.BlockSpec((None, block_rows, k, k), lambda s, i: (s, i, 0, 0)),
+            pl.BlockSpec((None, block_rows, k), lambda s, i: (s, i, 0)),
+        ]
+        lead = (n_draws,)
+    else:
+        grid = (n_blocks,)
+        row_block = lambda i: (i, 0)  # noqa: E731
+        out_specs = [
+            pl.BlockSpec((block_rows, k, k), lambda i: (i, 0, 0)),
+            pl.BlockSpec((block_rows, k), lambda i: (i, 0)),
+        ]
+        lead = ()
     return pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(pz.shape, jnp.float32),
-            jax.ShapeDtypeStruct(rz.shape, jnp.float32),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((block_rows, w), row_block, memory_space=pltpu.SMEM),
+            pl.BlockSpec((block_rows, w), row_block),
+            pl.BlockSpec((block_rows, w), row_block),
+            pl.BlockSpec(memory_space=pl.ANY),   # v: gathered in-kernel
         ],
-        # indices count the scalar-prefetch arg: 5/6 are the zero inits
-        input_output_aliases={5: 0, 6: 1},
+        out_specs=out_specs,
+        out_shape=[
+            jax.ShapeDtypeStruct(lead + (r, k, k), jnp.float32),
+            jax.ShapeDtypeStruct(lead + (r, k), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((2, block_rows, block_w, lanes), v.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
         interpret=interpret,
-    )(seg_ids, indices, values, mask, v, pz, rz)
+    )(indices, values, mask, v)

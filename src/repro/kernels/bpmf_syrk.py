@@ -24,24 +24,31 @@ from jax.experimental import pallas as pl
 
 def _syrk_kernel(vm_ref, rv_ref, prec_ref, rhs_ref):
     j = pl.program_id(1)
-    vm = vm_ref[...]                     # (BR, BW, K)
+    br = vm_ref.shape[0]
     rv = rv_ref[...]                     # (BR, BW)
-    prec = jax.lax.dot_general(
-        vm, vm,
-        dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )                                    # (BR, K, K)
-    rhs = jnp.einsum("rwk,rw->rk", vm, rv)
+    prec, rhs = [], []
+    for r in range(br):  # one 2-D MXU product per row of the block
+        vm = vm_ref[r]                   # (BW, K)
+        prec.append(jax.lax.dot_general(
+            vm, vm, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ))
+        rhs.append(jax.lax.dot_general(
+            rv[r:r + 1].astype(vm.dtype), vm, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ))
 
     @pl.when(j == 0)
     def _init():
-        prec_ref[...] = prec
-        rhs_ref[...] = rhs
+        for r in range(br):
+            prec_ref[r] = prec[r]
+            rhs_ref[r:r + 1, :] = rhs[r]
 
     @pl.when(j > 0)
     def _acc():
-        prec_ref[...] += prec
-        rhs_ref[...] += rhs
+        for r in range(br):
+            prec_ref[r] += prec[r]
+            rhs_ref[r:r + 1, :] += rhs[r]
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "block_w", "interpret"))

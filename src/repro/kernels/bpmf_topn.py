@@ -13,10 +13,11 @@ a running (B_blk, TOPK) candidate list held in the output refs, so only the
 candidates ever leave VMEM. The item axis is the fastest-varying grid
 dimension (sequential on TPU), which makes the in-place merge race-free.
 
-Tie-breaking matches `jax.lax.top_k` bit-for-bit: the running list (earlier,
-i.e. lower, item indices) is placed before the fresh tile in the merge and
-`lax.top_k` is stable, so equal scores resolve to the lowest item index —
-the same order a monolithic top_k over the full score row would produce.
+Each merge runs `topk` rounds of max-and-lowest-index selection over the
+running list and the fresh tile (`_select_topk`): Mosaic lowers a max, a
+min and a select, not `lax.top_k`. Taking the lowest item id among equal
+scores reproduces `jax.lax.top_k` over the full score row bit-for-bit,
+ties included.
 """
 from __future__ import annotations
 
@@ -25,6 +26,35 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+
+def _select_topk(cand, topk: int):
+    """`topk` rounds of max-and-lowest-index selection over candidate
+    (value, item) arrays of shape (BB, *). Equal to `lax.top_k` over the
+    same candidates, ties included: every round takes the largest value and,
+    among equal values, the lowest item id, then retires that item."""
+    bb = cand[0][0].shape[0]
+    col = jax.lax.broadcasted_iota(jnp.int32, (bb, topk), 1)
+    big = jnp.iinfo(jnp.int32).max
+
+    def round_(t, carry):
+        cand, out_v, out_i = carry
+        best = functools.reduce(
+            jnp.maximum, [jnp.max(v, axis=1, keepdims=True) for v, _ in cand])
+        pick = functools.reduce(jnp.minimum, [
+            jnp.min(jnp.where(v == best, i, big), axis=1, keepdims=True)
+            for v, i in cand])
+        cand = tuple(
+            (jnp.where(i == pick, -jnp.inf, v), jnp.where(i == pick, big, i))
+            for v, i in cand)
+        out_v = jnp.where(col == t, best, out_v)
+        out_i = jnp.where(col == t, pick, out_i)
+        return cand, out_v, out_i
+
+    init = (tuple(cand), jnp.zeros((bb, topk), jnp.float32),
+            jnp.zeros((bb, topk), jnp.int32))
+    _, out_v, out_i = jax.lax.fori_loop(0, topk, round_, init)
+    return out_v, out_i
 
 
 def _topn_kernel(u_ref, v_ref, val_ref, idx_ref, *, topk: int, n_valid: int,
@@ -40,23 +70,14 @@ def _topn_kernel(u_ref, v_ref, val_ref, idx_ref, *, topk: int, n_valid: int,
     cols = j * block_n + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     scores = jnp.where(cols < n_valid, scores, -jnp.inf)
 
-    # top_k / take_along_axis are interpret-only today: the known Mosaic
-    # gap tracked by the ROADMAP "TPU hardware verification" item (the
-    # planned restructure is iterative argmax selection). Validated in
-    # interpret mode; suppressions come out when the kernel is reshaped.
     @pl.when(j == 0)
-    def _first():
-        vals, pos = jax.lax.top_k(scores, topk)  # repro-lint: disable=pallas-lowering
-        val_ref[...] = vals
-        idx_ref[...] = jnp.take_along_axis(cols, pos, axis=1)  # repro-lint: disable=pallas-lowering
+    def _init():  # an empty running list: no entry outranks a real score
+        val_ref[...] = jnp.full(val_ref.shape, -jnp.inf, jnp.float32)
+        idx_ref[...] = jnp.full(idx_ref.shape, jnp.iinfo(jnp.int32).max,
+                                jnp.int32)
 
-    @pl.when(j > 0)
-    def _merge():
-        cand_v = jnp.concatenate([val_ref[...], scores], axis=1)
-        cand_i = jnp.concatenate([idx_ref[...], cols], axis=1)
-        vals, pos = jax.lax.top_k(cand_v, topk)  # repro-lint: disable=pallas-lowering
-        val_ref[...] = vals
-        idx_ref[...] = jnp.take_along_axis(cand_i, pos, axis=1)  # repro-lint: disable=pallas-lowering
+    run = (val_ref[...], idx_ref[...])
+    val_ref[...], idx_ref[...] = _select_topk([run, (scores, cols)], topk)
 
 
 _trace_count = 0

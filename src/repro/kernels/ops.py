@@ -1,7 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Handles shape padding to kernel tile multiples and selects interpret mode on
-non-TPU backends (this container is CPU-only; TPU is the deployment target).
+non-TPU backends. On a TPU backend every kernel is compiled by Mosaic and
+none runs interpreted.
 """
 from __future__ import annotations
 
@@ -152,21 +153,15 @@ def topn_scores(u: jax.Array, v: jax.Array, topk: int,
     return vals[:b], idx[:b]
 
 
-def _gather_syrk_seg_jnp(
-    indices, values, mask, seg_ids, n_segments, v,
-    *, bf16_gather, identity_segments,
-):
-    """Fused-semantics jnp path (the off-TPU engine and the XLA fallback).
+def _gather_syrk_rows_jnp(indices, values, mask, v, *, bf16_gather):
+    """Row-level (prec, rhs) on the jnp path (the off-TPU engine).
 
     Same contraction order as the kernel: gather → masked MXU-style
-    dot_general with fp32 accumulation → sorted segment reduction (skipped
-    when every row is its own segment — the common narrow-bucket case, where
-    the "reduction" is the identity).
+    dot_general with fp32 accumulation.
     """
-    stacked = v.ndim == 3
     if bf16_gather:
         v = v.astype(jnp.bfloat16)
-    g = v[:, indices] if stacked else v[indices]      # (..., R, W, K)
+    g = v[:, indices] if v.ndim == 3 else v[indices]   # (..., R, W, K)
     gm = g * mask[..., None].astype(g.dtype)
     rv = values * mask
     nb = g.ndim - 2                                    # batch dims: (...,) + R
@@ -181,19 +176,29 @@ def _gather_syrk_seg_jnp(
         (((nb,), (nb,)), (batch, batch)),
         preferred_element_type=jnp.float32,
     )[..., 0]
-    # one shared definition of the segment reduction (lazy import: gibbs
-    # imports this module lazily too, so neither import is circular)
-    from repro.core.gibbs import segment_reduce_rows
+    return prec_rows, rhs_rows
 
-    prec = segment_reduce_rows(
-        prec_rows, seg_ids, n_segments,
-        stacked=stacked, identity=identity_segments,
+
+def _gather_syrk_rows_pallas(indices, values, mask, v, *, bf16_gather,
+                             interpret):
+    """Row-level (prec, rhs) from the Pallas kernel, padded to its tiles."""
+    from repro.kernels.bpmf_gather_syrk import LANES, gather_syrk_pallas
+
+    r, w = indices.shape
+    block_rows = 8
+    block_w = _block_w_for(w)
+    # pad rows/columns carry mask 0 and contribute exact zeros
+    indices = _pad_to(_pad_to(indices, 0, block_rows), 1, block_w)
+    values = _pad_to(_pad_to(values, 0, block_rows), 1, block_w)
+    mask = _pad_to(_pad_to(mask, 0, block_rows), 1, block_w)
+    # an HBM row is DMA-able only as whole 128-lane tiles
+    k = v.shape[-1]
+    v = _pad_to(v.astype(jnp.float32), v.ndim - 1, LANES)
+    prec_rows, rhs_rows = gather_syrk_pallas(
+        indices, values, mask, v, k=k, block_rows=block_rows,
+        block_w=block_w, bf16=bf16_gather, interpret=interpret,
     )
-    rhs = segment_reduce_rows(
-        rhs_rows, seg_ids, n_segments,
-        stacked=stacked, identity=identity_segments,
-    )
-    return prec, rhs
+    return prec_rows[..., :r, :, :], rhs_rows[..., :r, :]
 
 
 def gather_syrk_seg(
@@ -210,54 +215,37 @@ def gather_syrk_seg(
 ):
     """Fused gather→syrk→segment-reduce: per-SEGMENT (prec, rhs) directly.
 
-    The sweep's fused engine. On TPU this is the Pallas kernel (V gathered
-    from ANY space, in-kernel segment reduction — the gathered block and the
-    row-level (R, K, K) intermediate never touch HBM); elsewhere a
-    fused-semantics jnp path with identical contraction order. Pass
-    ``interpret=True`` to force the real kernel in interpret mode (the
-    equivalence tests); None/False off-TPU both mean the jnp path — a
-    compiled Mosaic kernel does not exist there. Rows must be
-    segment-sorted — the bucket/grid planner invariant; `bf16_gather`
-    halves the dominant gather traffic and keeps fp32 accumulation
-    (tolerance documented in docs/architecture.md).
+    The sweep's fused engine. On TPU the row-level statistics come from the
+    Pallas kernel (V gathered row by row from ANY space inside the kernel,
+    so the gathered (R, W, K) block never touches HBM); elsewhere from a
+    jnp path with identical contraction order. Pass ``interpret=True`` to
+    force the real kernel in interpret mode (the equivalence tests);
+    None/False off-TPU both mean the jnp path — a compiled Mosaic kernel
+    does not exist there. Either way XLA's sorted segment reduction follows
+    (skipped when every row is its own segment — the common narrow-bucket
+    case). Rows must be segment-sorted — the bucket/grid planner invariant;
+    `bf16_gather` rounds the gathered factors to bf16 and keeps fp32
+    accumulation (tolerance documented in docs/architecture.md).
 
     Returns prec (..., n_segments, K, K), rhs (..., n_segments, K), with the
     leading stacked-draw axis present iff ``v`` carried one.
     """
-    use_pallas = interpret is True or _on_tpu()
-    if not use_pallas:
-        return _gather_syrk_seg_jnp(
-            indices, values, mask, seg_ids, n_segments, v,
-            bf16_gather=bf16_gather, identity_segments=identity_segments,
-        )
+    # one shared definition of the segment reduction (lazy import: gibbs
+    # imports this module lazily too, so neither import is circular)
+    from repro.core.gibbs import segment_reduce_rows
 
-    from repro.kernels.bpmf_gather_syrk import gather_syrk_seg_pallas
-
-    interpret = (not _on_tpu()) if interpret is None else bool(interpret)
-    r, w = indices.shape
-    block_rows = 8
-    block_w = _block_w_for(w)
-    pad_r = (-r) % block_rows
-    if pad_r:
-        indices = jnp.pad(indices, ((0, pad_r), (0, 0)))
-        values = jnp.pad(values, ((0, pad_r), (0, 0)))
-        mask = jnp.pad(mask, ((0, pad_r), (0, 0)))
-        # pad rows carry mask 0 and repeat the LAST segment id, keeping the
-        # nondecreasing invariant while contributing exact zeros
-        seg_ids = jnp.pad(seg_ids, (0, pad_r), mode="edge")
-    indices = _pad_to(indices, 1, block_w)
-    values = _pad_to(values, 1, block_w)
-    mask = _pad_to(mask, 1, block_w)
-    if bf16_gather:
-        v = v.astype(jnp.bfloat16)   # one cast; every gathered read is half-width
-    n_seg_padded = n_segments + block_rows
-    n_seg_padded += (-n_seg_padded) % 8
-    prec, rhs = gather_syrk_seg_pallas(
-        indices, values, mask, seg_ids, v,
-        n_seg_padded=n_seg_padded, block_rows=block_rows, block_w=block_w,
-        interpret=interpret,
+    if interpret is True or _on_tpu():
+        rows = _gather_syrk_rows_pallas(indices, values, mask, v,
+                                        bf16_gather=bf16_gather,
+                                        interpret=bool(interpret))
+    else:
+        rows = _gather_syrk_rows_jnp(indices, values, mask, v,
+                                     bf16_gather=bf16_gather)
+    return tuple(
+        segment_reduce_rows(x, seg_ids, n_segments, stacked=v.ndim == 3,
+                            identity=identity_segments)
+        for x in rows
     )
-    return prec[..., :n_segments, :, :], rhs[..., :n_segments, :]
 
 
 def gather_syrk(indices: jax.Array, values: jax.Array, mask: jax.Array,

@@ -23,7 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.distributed import AXIS, DistState, make_sweep
 from repro.core.hyper import HyperParams, default_prior
 from repro.launch.hlo_analysis import HloCostModel, roofline_terms
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import TARGET_KIND, chip_peaks
 
 ART_DIR = Path(__file__).resolve().parents[3] / "artifacts" / "dryrun"
 
@@ -129,14 +129,15 @@ def run_cell(dataset: str, mode: str, multi_pod: bool, k: int = 64, width: int =
         # useful flops: per item update 2*deg*W... analytic: syrk 2*nnz*W_eff*K^2/W... use
         # 2 * nnz * K^2 (outer products) + (M+N) * (2/3 K^3 + 4K^2) (cholesky+solves)
         model_flops = 2.0 * nnz * k * k + (m + n) * (2 / 3 * k**3 + 4 * k * k)
+        peaks = chip_peaks(TARGET_KIND)
         terms = roofline_terms(
             flops=float(cost["flops"]),
             hbm_bytes=float(cost["hbm_bytes"]),
             collective_bytes_per_device=float(cost["collective_total_bytes"]),
             n_devices=p,
-            peak_flops=PEAK_FLOPS_BF16,
-            hbm_bw=HBM_BW,
-            ici_bw=ICI_BW,
+            peak_flops=peaks.flops_bf16,
+            hbm_bw=peaks.hbm_bw,
+            ici_bw=peaks.ici_bw,
         )
         rec.update(
             ok=True,

@@ -26,7 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import REGISTRY, get_config
 from repro.launch.hlo_analysis import HloCostModel, roofline_terms
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh
+from repro.launch.mesh import TARGET_KIND, chip_peaks, make_production_mesh
 from repro.launch.train import (
     make_train_step,
     shardings_of,
@@ -145,14 +145,15 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, save_hlo: bool = False,
         from repro.models.api import model_flops_per_step
 
         model_flops = model_flops_per_step(cfg, shape)
+        peaks = chip_peaks(TARGET_KIND)
         terms = roofline_terms(
             flops=flops,
             hbm_bytes=hbm_bytes,
             collective_bytes_per_device=float(cost["collective_total_bytes"]),
             n_devices=n_dev,
-            peak_flops=PEAK_FLOPS_BF16,
-            hbm_bw=HBM_BW,
-            ici_bw=ICI_BW,
+            peak_flops=peaks.flops_bf16,
+            hbm_bw=peaks.hbm_bw,
+            ici_bw=peaks.ici_bw,
         )
         rec.update(
             ok=True,

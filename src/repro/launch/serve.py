@@ -208,22 +208,31 @@ def run_train_and_serve(
 
 def _ensure_host_devices(n_hosts: int) -> None:
     """Re-exec under XLA_FLAGS=--xla_force_host_platform_device_count=N
-    when fewer devices exist than simulated hosts requested. Device count
-    is fixed once the backend initialises, so this must replace the
-    process; the guard env var prevents an exec loop when the flag cannot
-    produce enough devices (e.g. on real accelerators)."""
-    if len(jax.devices()) >= n_hosts:
+    when the CPU backend has fewer devices than simulated hosts requested.
+    Device count is fixed once the backend initialises, so this must
+    replace the process; the guard env var prevents an exec loop. Forced
+    host devices exist only on the CPU: on an accelerator backend, fewer
+    devices than hosts is an error."""
+    n_dev = len(jax.devices())
+    if n_dev >= n_hosts:
         return
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"--hosts {n_hosts} needs {n_hosts} devices but only {n_dev} "
+            f"{backend} device(s) exist"
+        )
     if os.environ.get("_REPRO_SERVE_HOSTS_REEXEC") == "1":
         raise RuntimeError(
             f"--hosts {n_hosts} needs {n_hosts} devices but only "
-            f"{len(jax.devices())} exist even after forcing host devices"
+            f"{n_dev} exist even after forcing host devices"
         )
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={n_hosts}"
     ).strip()
+    env["JAX_PLATFORMS"] = "cpu"
     env["_REPRO_SERVE_HOSTS_REEXEC"] = "1"
     os.execvpe(sys.executable,
                [sys.executable, "-m", "repro.launch.serve", *sys.argv[1:]],
@@ -455,8 +464,8 @@ def main():
                     help="train and serve in one process; retained draws are "
                          "pushed to the live frontend (no disk poll)")
     ap.add_argument("--hosts", type=int, default=0,
-                    help="serve through the multi-host tier with N simulated "
-                         "hosts (re-execs under "
+                    help="serve through the multi-host tier with N hosts, one "
+                         "per device (on the CPU backend, re-execs under "
                          "--xla_force_host_platform_device_count when needed)")
     ap.add_argument("--publishes", type=int, default=4,
                     help="--hosts mode: fresh epochs pushed mid-stream")
@@ -469,6 +478,9 @@ def main():
     ap.add_argument("--keep", type=int, default=4,
                     help="co-train: publication window / ensemble size")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.bpmf and args.hosts > 0:
         _ensure_host_devices(args.hosts)

@@ -218,6 +218,9 @@ def main():
     args = ap.parse_args()
     if not args.bpmf:
         raise SystemExit("only --bpmf has a CLI; LM training is library-only")
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     bpmf_train_main(args)
 
 

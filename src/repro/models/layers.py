@@ -697,9 +697,7 @@ def _moe_ep_shard_map(
     bspec = batch_axes if batch_axes else None
     w_spec = P(SEQ_AXIS, fsdp_axes if w_shard_ok else None, None)
     wd_spec = P(SEQ_AXIS, None, fsdp_axes if w_shard_ok else None)
-    from repro.compat import shard_map as _shard_map
-
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         region,
         mesh=mesh,
         in_specs=(

@@ -10,6 +10,7 @@ import pytest
 
 from repro.analysis import ALL_RULES, RULE_DOCS, analyze_source, main
 from repro.analysis import baseline as baseline_mod
+from repro.analysis.pallas import RULES as PALLAS_RULES
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -779,7 +780,7 @@ def test_collective_axis_silent_without_declarations():
 STATE_INIT = src("""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     AXIS = "items"
 
@@ -937,15 +938,19 @@ def test_pallas_lowering_sort_flagged_only_inside_kernel():
 
 
 def test_pallas_lowering_catches_mutant_in_live_topn_kernel():
-    """Seeded mutant: drop the sanctioned suppressions in bpmf_topn.py and
-    the interpret-only top_k/take_along_axis sites must all surface."""
+    """The live top-N kernel lints clean with no suppression comment, and
+    a seeded mutant that puts the interpret-only lax.top_k back into its
+    merge surfaces."""
     live = (ROOT / "src" / "repro" / "kernels" / "bpmf_topn.py").read_text()
-    assert analyze_source(live, rules=["pallas-lowering"]) == []
-    mutant = live.replace("  # repro-lint: disable=pallas-lowering", "")
+    assert "repro-lint: disable" not in live
+    assert analyze_source(live, rules=list(PALLAS_RULES)) == []
+    mutant = live.replace(
+        "val_ref[...], idx_ref[...] = _select_topk([run, (scores, cols)], topk)",
+        "val_ref[...], idx_ref[...] = jax.lax.top_k(scores, topk)",
+    )
     assert mutant != live
-    found = analyze_source(mutant, rules=["pallas-lowering"])
-    assert len(found) == 4
-    assert {f.rule for f in found} == {"pallas-lowering"}
+    (f,) = analyze_source(mutant, rules=["pallas-lowering"])
+    assert f.rule == "pallas-lowering" and "top_k" in f.message
 
 
 def test_pallas_anyspace_direct_access_flagged():
@@ -979,14 +984,20 @@ def test_pallas_anyspace_vmem_refs_untouched():
 
 
 def test_pallas_anyspace_catches_mutant_in_live_gather_syrk():
+    """The live gather-syrk kernel lints clean with no suppression comment
+    (V is touched only through `.at[...]` DMA slices), and a seeded mutant
+    that reads the ANY-space ref directly surfaces."""
     live = (ROOT / "src" / "repro" / "kernels"
             / "bpmf_gather_syrk.py").read_text()
-    assert analyze_source(live, rules=["pallas-anyspace"]) == []
-    mutant = live.replace("  # repro-lint: disable=pallas-anyspace", "")
+    assert "repro-lint: disable" not in live
+    assert analyze_source(live, rules=list(PALLAS_RULES)) == []
+    mutant = live.replace(
+        "g = gather_buf[wt % 2, r][:, :k]",
+        "g = v_ref[pl.ds(0, block_w), :k]",
+    )
     assert mutant != live
-    found = analyze_source(mutant, rules=["pallas-anyspace"])
-    assert len(found) == 2
-    assert {f.rule for f in found} == {"pallas-anyspace"}
+    (f,) = analyze_source(mutant, rules=["pallas-anyspace"])
+    assert f.rule == "pallas-anyspace" and "'v_ref'" in f.message
 
 
 def test_pallas_out_init_accumulate_into_garbage_flagged():

@@ -396,3 +396,31 @@ def test_cluster_freshness_clock_records_barrier_latency():
     assert cluster.commits == 2
     assert len(cluster.publish_to_fresh_s) == 2
     assert 0 < fresh["p50"] <= fresh["max"] < 20.0
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    """Roofline peaks come from a table keyed by `device_kind`; a kind that
+    is not in the table is an error, never a silent default."""
+    from repro.launch.mesh import TARGET_KIND, chip_peaks
+
+    v5e = chip_peaks(TARGET_KIND)
+    assert (v5e.flops_bf16, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="no published peaks"):
+        chip_peaks("cpu")
+
+
+def test_serving_hosts_share_devices_only_on_cpu(monkeypatch):
+    """Forced host devices exist only on the CPU backend, where hosts may
+    cycle over fewer devices; on an accelerator, fewer devices than hosts
+    is an error rather than two hosts silently sharing one chip."""
+    import jax
+
+    from repro.launch import mesh
+
+    n = len(jax.devices())
+    hosts = mesh.serving_host_devices(n_hosts=n + 1)
+    assert len(hosts) == n + 1 and hosts[n] == hosts[0]
+    monkeypatch.setattr(mesh.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="serving hosts need"):
+        mesh.serving_host_devices(n_hosts=n + 1)
+    assert len(mesh.serving_host_devices(n_hosts=n)) == n

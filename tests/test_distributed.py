@@ -168,7 +168,6 @@ def test_int8_compressed_psum_error_feedback():
     out = run_sub("""
     import numpy as np, jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.optim.compress import compress_init, compressed_psum, CompressState
 
     mesh = jax.make_mesh((8,), ("pod",))
@@ -178,7 +177,7 @@ def test_int8_compressed_psum_error_feedback():
         out, st = compressed_psum({"w": g[0]}, CompressState(error={"w": err[0]}), "pod")
         return out["w"][None], st.error["w"][None]
 
-    m = shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
+    m = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
                   out_specs=(P("pod"), P("pod")), check_vma=False)
     errs = np.zeros_like(g_global)
     # accumulate over rounds: error feedback keeps the running sum unbiased
@@ -233,3 +232,34 @@ def test_moe_ep_shard_map_matches_grouped():
     print("ep ok")
     """)
     assert "ep ok" in out
+
+
+def test_finish_phase_chunked_solve_matches_whole(monkeypatch):
+    """A shard's items are solved in fixed-size chunks (the last clamped to
+    end at n_loc, re-solving some items) so a full-size shard's Cholesky
+    factors never coexist with its statistics; the draws must not depend
+    on the chunking, and pad slots (id -1) stay zero."""
+    import jax
+    import jax.numpy as jnp
+
+    import repro.core.gibbs as gibbs
+    from repro.core.distributed import _finish_phase
+    from repro.core.hyper import init_hyper
+
+    rng = np.random.default_rng(0)
+    n, k = 37, 8
+    a = rng.normal(size=(n, k, 3 * k)).astype(np.float32)
+    prec = jnp.asarray(a @ a.transpose(0, 2, 1))
+    rhs = jnp.asarray(rng.normal(size=(n, k)).astype(np.float32))
+    ids = jnp.asarray(np.where(np.arange(n) < 33, np.arange(n), -1), jnp.int32)
+
+    def finish():  # the same key both times: the draws must match exactly
+        return np.asarray(_finish_phase(jax.random.PRNGKey(3), prec, rhs, ids,
+                                        init_hyper(k), 2.0))
+
+    whole = finish()
+    monkeypatch.setattr(gibbs, "CHUNK_BYTES", 4 * k * k * 8)   # 8-item chunks
+    assert gibbs.chunk_rows(k, k) == 8
+    chunked = finish()
+    np.testing.assert_allclose(chunked, whole, rtol=1e-5, atol=1e-5)
+    assert np.isfinite(whole).all() and not whole[33:].any()

@@ -512,22 +512,30 @@ def test_no_torn_ensemble_during_concurrent_publishes():
             time.sleep(0.002)
         ch.close()
 
-    pub = threading.Thread(target=publisher)
-    pub.start()
     served = []
+
+    def serve_batch():
+        for u in range(3):
+            fe.submit(u, topk=1)
+        for res in fe.flush():
+            served.append(res)
+            # consistency: reported epoch, item, and score all agree
+            assert res.items[0] == res.epoch % N, res
+            assert res.scores[0] == pytest.approx(float(res.epoch)), res
+
+    pub = threading.Thread(target=publisher)
     try:
+        # the first batch compiles the scoring step; serve it before the
+        # publish stream starts so the stream is not spent compiling
+        serve_batch()
+        pub.start()
         t_end = time.monotonic() + 3.0
         while time.monotonic() < t_end and not ch.closed:
-            for u in range(3):
-                fe.submit(u, topk=1)
-            for res in fe.flush():
-                served.append(res)
-                # consistency: reported epoch, item, and score all agree
-                assert res.items[0] == res.epoch % N, res
-                assert res.scores[0] == pytest.approx(float(res.epoch)), res
+            serve_batch()
     finally:
         stop.set()
-        pub.join(timeout=10.0)
+        if pub.ident is not None:
+            pub.join(timeout=10.0)
         fe.close()
 
     epochs = [r.epoch for r in served]

@@ -86,6 +86,33 @@ def test_update_factors_engines_match(small_data):
     np.testing.assert_allclose(out["fused"], out["reference"], atol=2e-4, rtol=2e-4)
 
 
+@pytest.mark.parametrize("engine", ["einsum", "fused"])
+def test_update_factors_row_chunks_match_whole_buckets(small_data, engine,
+                                                       monkeypatch):
+    """Buckets longer than one chunk are swept in row chunks (the HBM bound
+    at full dataset size); the draws must not depend on the chunking, for
+    identity-segment buckets and for the bucket that splits items."""
+    import repro.core.gibbs as gibbs
+
+    train, _ = small_data
+    s = GibbsSampler(train, None, k=16, alpha=8.0, widths=(8, 32))
+    state = s.init(0)
+    key = jax.random.PRNGKey(7)
+    buckets = s.user_buckets
+    assert not all(b.identity_segments for b in buckets)
+
+    def run():
+        new, _ = update_factors(key, state.v, buckets, s.m, state.hyper_u,
+                                8.0, engine=engine)
+        return np.asarray(new)
+
+    whole = run()
+    monkeypatch.setattr(gibbs, "CHUNK_BYTES", 4 * 16 * 32 * 8)  # 8-row chunks
+    assert all(b.indices.shape[0] > gibbs.chunk_rows(b.width, 16)
+               for b in buckets)
+    np.testing.assert_allclose(run(), whole, atol=1e-5, rtol=1e-5)
+
+
 @pytest.mark.parametrize("engine", ["einsum", "fused", "kernel"])
 def test_gibbs_sweeps_identical_across_engines(small_data, engine):
     """Two full sweeps from one seed: every engine draws the same samples
