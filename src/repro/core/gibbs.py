@@ -14,11 +14,13 @@ statistics for the *next* hyperparameter draw are fused into the sweep.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax._src import config as jax_config
 
 from repro.core.buckets import Bucket, BucketPlan, WidthsSpec, plan_buckets
 from repro.core.hyper import (
@@ -28,6 +30,7 @@ from repro.core.hyper import (
     sample_normal_wishart,
 )
 from repro.data.sparse import SparseRatings, csr_from_coo
+
 
 # Sweep engines, selecting how per-segment rating statistics are computed
 # and how the posterior systems are solved (docs/architecture.md §4):
@@ -66,6 +69,23 @@ def resolve_engine(engine: str | None, use_kernel: bool = False) -> str:
     if engine not in ENGINES:
         raise ValueError(f"engine must be one of {ENGINES}, got {engine!r}")
     return engine
+
+
+@contextlib.contextmanager
+def scopes_in_cache_key():
+    """Compile what runs inside under a persistent-cache key that holds its
+    name scopes (`bpmf.*`, `serve.foldin`).
+
+    A profile attributes device time by those scopes, read from the HLO
+    proto of the executable that ran. JAX's cache key leaves such metadata
+    out by default, so an executable cached from the same computation under
+    other scopes, or none, would be loaded and profiled in its place. Source
+    locations are left out of the metadata here, so that the key does not
+    depend on file paths or call stacks.
+    """
+    with jax_config.compilation_cache_include_metadata_in_key(True), \
+            jax_config.traceback_in_locations_limit(0):
+        yield
 
 
 class FactorStats(NamedTuple):
@@ -183,61 +203,62 @@ def bucket_stats(
     routes both forms through `kernels.ops.gather_syrk_seg`, so the
     stacked-draw fold-in rides the same kernel as the training sweep.
     """
-    engine = resolve_engine(engine, use_kernel)
+    with jax.named_scope("bpmf.stats"):
+        engine = resolve_engine(engine, use_kernel)
 
-    if engine == "fused":
-        from repro.kernels import ops as kops
+        if engine == "fused":
+            from repro.kernels import ops as kops
 
-        return kops.gather_syrk_seg(
-            bucket.indices, bucket.values, bucket.mask,
-            bucket.seg_ids, bucket.n_segments, counterpart,
-            bf16_gather=bf16_gather,
-            identity_segments=bucket.identity_segments,
-            interpret=interpret,
-        )
+            return kops.gather_syrk_seg(
+                bucket.indices, bucket.values, bucket.mask,
+                bucket.seg_ids, bucket.n_segments, counterpart,
+                bf16_gather=bf16_gather,
+                identity_segments=bucket.identity_segments,
+                interpret=interpret,
+            )
 
-    # identity reduction is exact (a permutation-free relabeling), so the
-    # restructured einsum engine skips it; the reference engine keeps the
-    # seed computation verbatim
-    skip_reduce = engine == "einsum" and bucket.identity_segments
-    sorted_ids = engine != "reference"
+        # identity reduction is exact (a permutation-free relabeling), so the
+        # restructured einsum engine skips it; the reference engine keeps the
+        # seed computation verbatim
+        skip_reduce = engine == "einsum" and bucket.identity_segments
+        sorted_ids = engine != "reference"
 
-    def reduce(rows, rotate):
-        return segment_reduce_rows(
-            rows, bucket.seg_ids, bucket.n_segments, stacked=rotate,
-            sorted_ids=sorted_ids, identity=skip_reduce,
-        )
+        def reduce(rows, rotate):
+            return segment_reduce_rows(
+                rows, bucket.seg_ids, bucket.n_segments, stacked=rotate,
+                sorted_ids=sorted_ids, identity=skip_reduce,
+            )
 
-    rv = bucket.values * bucket.mask
-    if counterpart.ndim == 2:
-        vg = counterpart[bucket.indices]                # (rows, w, K)
+        rv = bucket.values * bucket.mask
+        if counterpart.ndim == 2:
+            vg = counterpart[bucket.indices]                # (rows, w, K)
+            vm = vg * bucket.mask[..., None]
+            if engine == "kernel":
+                from repro.kernels import ops as kops
+
+                prec_rows, rhs_rows = kops.masked_syrk(vm, rv)
+            else:
+                prec_rows = jnp.einsum(
+                    "rwk,rwl->rkl", vm, vm, preferred_element_type=jnp.float32
+                )
+                rhs_rows = jnp.einsum("rwk,rw->rk", vm, rv)
+            return reduce(prec_rows, False), reduce(rhs_rows, False)
+
+        # stacked draws: one gather + one contraction covering all S draws
+        vg = counterpart[:, bucket.indices]                 # (S, rows, w, K)
         vm = vg * bucket.mask[..., None]
         if engine == "kernel":
             from repro.kernels import ops as kops
 
-            prec_rows, rhs_rows = kops.masked_syrk(vm, rv)
+            prec_rows, rhs_rows = kops.masked_syrk(
+                vm, jnp.broadcast_to(rv, vm.shape[:-1])
+            )
         else:
             prec_rows = jnp.einsum(
-                "rwk,rwl->rkl", vm, vm, preferred_element_type=jnp.float32
+                "srwk,srwl->srkl", vm, vm, preferred_element_type=jnp.float32
             )
-            rhs_rows = jnp.einsum("rwk,rw->rk", vm, rv)
-        return reduce(prec_rows, False), reduce(rhs_rows, False)
-
-    # stacked draws: one gather + one contraction covering all S draws
-    vg = counterpart[:, bucket.indices]                 # (S, rows, w, K)
-    vm = vg * bucket.mask[..., None]
-    if engine == "kernel":
-        from repro.kernels import ops as kops
-
-        prec_rows, rhs_rows = kops.masked_syrk(
-            vm, jnp.broadcast_to(rv, vm.shape[:-1])
-        )
-    else:
-        prec_rows = jnp.einsum(
-            "srwk,srwl->srkl", vm, vm, preferred_element_type=jnp.float32
-        )
-        rhs_rows = jnp.einsum("srwk,rw->srk", vm, rv)
-    return reduce(prec_rows, True), reduce(rhs_rows, True)
+            rhs_rows = jnp.einsum("srwk,rw->srk", vm, rv)
+        return reduce(prec_rows, True), reduce(rhs_rows, True)
 
 
 def chol_subst_solve(chol: jax.Array, rhs: jax.Array, z: jax.Array) -> jax.Array:
@@ -298,29 +319,30 @@ def sample_mvn_precision(
     """
     if solver is None:
         solver = "kernel" if use_kernel else "subst"
-    if z is None:
-        z = (
-            jnp.zeros_like(rhs)
-            if key is None
-            else jax.random.normal(key, rhs.shape, rhs.dtype)
-        )
-    if solver == "kernel":
-        from repro.kernels import ops as kops
+    with jax.named_scope("bpmf.solve"):
+        if z is None:
+            z = (
+                jnp.zeros_like(rhs)
+                if key is None
+                else jax.random.normal(key, rhs.shape, rhs.dtype)
+            )
+        if solver == "kernel":
+            from repro.kernels import ops as kops
 
-        return kops.chol_solve_sample(prec, rhs, z)
-    chol = jnp.linalg.cholesky(prec)
-    if solver == "subst":
-        return chol_subst_solve(chol, rhs, z)
-    y = jax.lax.linalg.triangular_solve(
-        chol, rhs[..., None], left_side=True, lower=True
-    )
-    mean = jax.lax.linalg.triangular_solve(
-        chol, y, left_side=True, lower=True, transpose_a=True
-    )
-    noise = jax.lax.linalg.triangular_solve(
-        chol, z[..., None], left_side=True, lower=True, transpose_a=True
-    )
-    return (mean + noise)[..., 0]
+            return kops.chol_solve_sample(prec, rhs, z)
+        chol = jnp.linalg.cholesky(prec)
+        if solver == "subst":
+            return chol_subst_solve(chol, rhs, z)
+        y = jax.lax.linalg.triangular_solve(
+            chol, rhs[..., None], left_side=True, lower=True
+        )
+        mean = jax.lax.linalg.triangular_solve(
+            chol, y, left_side=True, lower=True, transpose_a=True
+        )
+        noise = jax.lax.linalg.triangular_solve(
+            chol, z[..., None], left_side=True, lower=True, transpose_a=True
+        )
+        return (mean + noise)[..., 0]
 
 
 # Largest per-chunk intermediate of a bucket update, in bytes. A full-size
@@ -428,38 +450,46 @@ def update_factors(
         rhs_all = jnp.zeros((n_items, k), dtype)
         for b in buckets:
             prec, rhs = bucket_stats(counterpart, b, engine="reference")
-            prec_all = prec_all.at[b.seg_item_ids].add(prec)
-            rhs_all = rhs_all.at[b.seg_item_ids].add(rhs)
-        prec_all = hyper.lam[None] + alpha * prec_all
-        rhs_all = (hyper.lam @ hyper.mu)[None] + alpha * rhs_all
+            with jax.named_scope("bpmf.prior"):
+                prec_all = prec_all.at[b.seg_item_ids].add(prec)
+                rhs_all = rhs_all.at[b.seg_item_ids].add(rhs)
+        with jax.named_scope("bpmf.prior"):
+            prec_all = hyper.lam[None] + alpha * prec_all
+            rhs_all = (hyper.lam @ hyper.mu)[None] + alpha * rhs_all
         new = sample_mvn_precision(key, prec_all, rhs_all, solver="lapack")
     else:
-        lam = hyper.lam.astype(dtype)
-        lam_mu = (hyper.lam @ hyper.mu).astype(dtype)
-        z = jax.random.normal(key, (n_items, k), dtype)
-        # items with no ratings keep the prior: one shared Cholesky factor
-        new = chol_subst_solve(
-            jnp.linalg.cholesky(lam), jnp.broadcast_to(lam_mu, (n_items, k)), z
-        )
+        with jax.named_scope("bpmf.prior"):
+            lam = hyper.lam.astype(dtype)
+            lam_mu = (hyper.lam @ hyper.mu).astype(dtype)
+            z = jax.random.normal(key, (n_items, k), dtype)
+            # items with no ratings keep the prior: one shared Cholesky factor
+            new = chol_subst_solve(
+                jnp.linalg.cholesky(lam), jnp.broadcast_to(lam_mu, (n_items, k)), z
+            )
         solver = "kernel" if engine == "kernel" else "subst"
 
         def sample(prec, rhs, item_ids):
-            prec = lam + (alpha * prec).astype(dtype)
-            rhs = lam_mu + (alpha * rhs).astype(dtype)
+            with jax.named_scope("bpmf.prior"):
+                prec = lam + (alpha * prec).astype(dtype)
+                rhs = lam_mu + (alpha * rhs).astype(dtype)
+                z_items = z[item_ids]
             return sample_mvn_precision(
-                None, prec, rhs, z=z[item_ids], solver=solver
+                None, prec, rhs, z=z_items, solver=solver
             )
 
         for b in buckets:
             x = _bucket_update(counterpart, b, sample, engine=engine,
                                bf16_gather=bf16_gather)
-            new = new.at[b.seg_item_ids].set(x, unique_indices=True)
+            with jax.named_scope("bpmf.prior"):
+                new = new.at[b.seg_item_ids].set(x, unique_indices=True)
 
-    stats = FactorStats(
-        sum_x=new.sum(0),
-        sum_xxt=jnp.einsum("nk,nl->kl", new, new, preferred_element_type=jnp.float32),
-        n=jnp.asarray(n_items, dtype),
-    )
+    with jax.named_scope("bpmf.hyper"):
+        stats = FactorStats(
+            sum_x=new.sum(0),
+            sum_xxt=jnp.einsum("nk,nl->kl", new, new,
+                               preferred_element_type=jnp.float32),
+            n=jnp.asarray(n_items, dtype),
+        )
     return new, stats
 
 
@@ -564,29 +594,34 @@ class GibbsSampler:
         key, k_hv, k_v, k_hu, k_u = jax.random.split(state.key, 5)
 
         # Movies phase: hyper from V stats, then update V given U.
-        sv = factor_stats(state.v)
-        hyper_v = sample_normal_wishart(k_hv, sv.sum_x, sv.sum_xxt, sv.n, self.prior)
+        with jax.named_scope("bpmf.hyper"):
+            sv = factor_stats(state.v)
+            hyper_v = sample_normal_wishart(k_hv, sv.sum_x, sv.sum_xxt, sv.n,
+                                            self.prior)
         v_new, _ = update_factors(
             k_v, state.u, item_buckets, self.n, hyper_v, self.alpha,
             engine=self.engine, bf16_gather=self.bf16_gather,
         )
 
         # Users phase: hyper from U stats, then update U given new V.
-        su = factor_stats(state.u)
-        hyper_u = sample_normal_wishart(k_hu, su.sum_x, su.sum_xxt, su.n, self.prior)
+        with jax.named_scope("bpmf.hyper"):
+            su = factor_stats(state.u)
+            hyper_u = sample_normal_wishart(k_hu, su.sum_x, su.sum_xxt, su.n,
+                                            self.prior)
         u_new, _ = update_factors(
             k_u, v_new, user_buckets, self.m, hyper_u, self.alpha,
             engine=self.engine, bf16_gather=self.bf16_gather,
         )
 
         # Posterior-predictive accumulation after burn-in.
-        preds = (
-            jnp.einsum("nk,nk->n", u_new[self.test_rows], v_new[self.test_cols])
-            + self.global_mean
-        )
-        collect = state.step >= self.burn_in
-        pred_sum = jnp.where(collect, state.pred_sum + preds, state.pred_sum)
-        pred_count = state.pred_count + jnp.where(collect, 1, 0)
+        with jax.named_scope("bpmf.predict"):
+            preds = (
+                jnp.einsum("nk,nk->n", u_new[self.test_rows], v_new[self.test_cols])
+                + self.global_mean
+            )
+            collect = state.step >= self.burn_in
+            pred_sum = jnp.where(collect, state.pred_sum + preds, state.pred_sum)
+            pred_count = state.pred_count + jnp.where(collect, 1, 0)
 
         return BPMFState(
             u=u_new,
@@ -600,7 +635,8 @@ class GibbsSampler:
         )
 
     def sweep(self, state: BPMFState) -> BPMFState:
-        return self._sweep(state, *self._plan_args)
+        with scopes_in_cache_key():
+            return self._sweep(state, *self._plan_args)
 
     def rmse(self, state: BPMFState) -> float:
         """Posterior-mean RMSE over the test set (paper's accuracy metric)."""

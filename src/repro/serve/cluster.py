@@ -523,17 +523,20 @@ class ClusterCoordinator:
         fetch = min(fetch, ens.n_items)
         vals, idx = self._gather_merge(picks, fetch, rows=rows,
                                        user_ids=user_ids)
-        vals = np.asarray(vals) + ens.global_mean
-        idx = np.asarray(idx)
+        # the host waits here for the device's candidates
+        with jax.profiler.TraceAnnotation("serve.fetch"):
+            vals = np.asarray(vals) + ens.global_mean
+            idx = np.asarray(idx)
         if exclude is None:
             return vals[:, :topk], idx[:, :topk]
-        out_v = np.full((b, topk), -np.inf, np.float32)
-        out_i = np.full((b, topk), -1, np.int32)
-        for r in range(b):
-            keep = ~np.isin(idx[r], exclude[r])
-            kept_v, kept_i = vals[r][keep][:topk], idx[r][keep][:topk]
-            out_v[r, : len(kept_v)] = kept_v
-            out_i[r, : len(kept_i)] = kept_i
+        with jax.profiler.TraceAnnotation("serve.exclude"):
+            out_v = np.full((b, topk), -np.inf, np.float32)
+            out_i = np.full((b, topk), -1, np.int32)
+            for r in range(b):
+                keep = ~np.isin(idx[r], exclude[r])
+                kept_v, kept_i = vals[r][keep][:topk], idx[r][keep][:topk]
+                out_v[r, : len(kept_v)] = kept_v
+                out_i[r, : len(kept_i)] = kept_i
         return out_v, out_i
 
     def recommend_rows(

@@ -56,6 +56,7 @@ from repro.core.gibbs import (
     bucket_stats,
     device_plan,
     sample_mvn_precision,
+    scopes_in_cache_key,
 )
 from repro.data.sparse import SparseRatings, csr_from_coo
 from repro.serve.ensemble import PosteriorEnsemble
@@ -204,26 +205,27 @@ def _fused_fold_in(
     """One batched (S*B) assembly + Cholesky solve for the whole fold-in."""
     global _trace_count
     _trace_count += 1  # executes at trace time only: one bump per jit miss
-    s, _, k = v.shape
-    prec = jnp.zeros((s, n_new, k, k), v.dtype)
-    rhs = jnp.zeros((s, n_new, k), v.dtype)
-    for (width, n_segments, identity), (idx, vals, mask, seg_ids, seg_item_ids) in zip(
-        plan_key, arrays
-    ):
-        b = DeviceBucket(
-            width=width, indices=idx, values=vals, mask=mask,
-            seg_ids=seg_ids, n_segments=n_segments, seg_item_ids=seg_item_ids,
-            identity_segments=identity,
-        )
-        # stacked-draw bucket stats: the fused engine rides the same
-        # gather-syrk kernel as the training sweep (leading S axis)
-        p, r = bucket_stats(v, b, engine=engine)  # (S, segs, ...)
-        prec = prec.at[:, seg_item_ids].add(p)
-        rhs = rhs.at[:, seg_item_ids].add(r)
-    prec = lam[:, None] + alpha * prec
-    rhs = jnp.einsum("skl,sl->sk", lam, mu)[:, None] + alpha * rhs
-    solver = "kernel" if engine == "kernel" else "subst"
-    return sample_mvn_precision(None, prec, rhs, z=z, solver=solver)
+    with jax.named_scope("serve.foldin"):
+        s, _, k = v.shape
+        prec = jnp.zeros((s, n_new, k, k), v.dtype)
+        rhs = jnp.zeros((s, n_new, k), v.dtype)
+        for (width, n_segments, identity), (
+            idx, vals, mask, seg_ids, seg_item_ids
+        ) in zip(plan_key, arrays):
+            b = DeviceBucket(
+                width=width, indices=idx, values=vals, mask=mask,
+                seg_ids=seg_ids, n_segments=n_segments, seg_item_ids=seg_item_ids,
+                identity_segments=identity,
+            )
+            # stacked-draw bucket stats: the fused engine rides the same
+            # gather-syrk kernel as the training sweep (leading S axis)
+            p, r = bucket_stats(v, b, engine=engine)  # (S, segs, ...)
+            prec = prec.at[:, seg_item_ids].add(p)
+            rhs = rhs.at[:, seg_item_ids].add(r)
+        prec = lam[:, None] + alpha * prec
+        rhs = jnp.einsum("skl,sl->sk", lam, mu)[:, None] + alpha * rhs
+        solver = "kernel" if engine == "kernel" else "subst"
+        return sample_mvn_precision(None, prec, rhs, z=z, solver=solver)
 
 
 def _check_fold_in_args(
@@ -312,54 +314,57 @@ def fold_in(
             if plan_cache is not None else n_new
         )
     else:
-        centered = (ratings.vals - ensemble.global_mean).astype(np.float32)
-        indptr, idx, vals = csr_from_coo(
-            ratings.rows, ratings.cols, centered, n_new
-        )
-        if plan_cache is not None:
-            widths = plan_cache.widths
-        plan = plan_buckets(
-            indptr, idx, vals, n_new, ensemble.n_items, widths
-        )
-        buckets = plan.buckets
-        if plan_cache is not None:
-            padded_batch, targets = plan_cache.schema(
-                tuple((b.width, b.rows, b.n_segments) for b in buckets),
-                n_new, ensemble.n_items,
+        # host planning: CSR, bucket plan, schema padding, copies to the device
+        with jax.profiler.TraceAnnotation("serve.foldin.plan"):
+            centered = (ratings.vals - ensemble.global_mean).astype(np.float32)
+            indptr, idx, vals = csr_from_coo(
+                ratings.rows, ratings.cols, centered, n_new
             )
-            buckets = tuple(
-                pad_bucket(b, rows, segs)
-                for b, (_, rows, segs) in zip(buckets, targets)
+            if plan_cache is not None:
+                widths = plan_cache.widths
+            plan = plan_buckets(
+                indptr, idx, vals, n_new, ensemble.n_items, widths
             )
-        else:
-            padded_batch = n_new
-        db = device_plan(buckets)
-        # under a plan cache the static key must be a function of the
-        # quantized SCHEMA alone: identity_segments is computed from the
-        # padded seg_ids contents, which can differ between two batches
-        # that share a schema (e.g. padding by one row makes seg_ids
-        # exactly arange) — letting it through would retrace on a cache
-        # hit and break the trace-flat contract
-        plan_key = tuple(
-            (b.width, b.n_segments,
-             False if plan_cache is not None else b.identity_segments)
-            for b in db
-        )
-        arrays = tuple(
-            (b.indices, b.values, b.mask, b.seg_ids, b.seg_item_ids)
-            for b in db
-        )
+            buckets = plan.buckets
+            if plan_cache is not None:
+                padded_batch, targets = plan_cache.schema(
+                    tuple((b.width, b.rows, b.n_segments) for b in buckets),
+                    n_new, ensemble.n_items,
+                )
+                buckets = tuple(
+                    pad_bucket(b, rows, segs)
+                    for b, (_, rows, segs) in zip(buckets, targets)
+                )
+            else:
+                padded_batch = n_new
+            db = device_plan(buckets)
+            # under a plan cache the static key must be a function of the
+            # quantized SCHEMA alone: identity_segments is computed from the
+            # padded seg_ids contents, which can differ between two batches
+            # that share a schema (e.g. padding by one row makes seg_ids
+            # exactly arange) — letting it through would retrace on a cache
+            # hit and break the trace-flat contract
+            plan_key = tuple(
+                (b.width, b.n_segments,
+                 False if plan_cache is not None else b.identity_segments)
+                for b in db
+            )
+            arrays = tuple(
+                (b.indices, b.values, b.mask, b.seg_ids, b.seg_item_ids)
+                for b in db
+            )
 
     if z is not None and padded_batch != n_new:
         z = jnp.concatenate(
             [z, jnp.zeros((s, padded_batch - n_new, k), z.dtype)], axis=1
         )
 
-    out = _fused_fold_in(
-        ensemble.v, ensemble.hyper_u_lam, ensemble.hyper_u_mu,
-        ensemble.alpha, arrays, z,
-        plan_key=plan_key, n_new=padded_batch, engine=engine,
-    )
+    with scopes_in_cache_key():
+        out = _fused_fold_in(
+            ensemble.v, ensemble.hyper_u_lam, ensemble.hyper_u_mu,
+            ensemble.alpha, arrays, z,
+            plan_key=plan_key, n_new=padded_batch, engine=engine,
+        )
     return out[:, :n_new]  # drop batch padding (padded rows solve the prior)
 
 

@@ -130,10 +130,9 @@ class RecommendFrontend:
         self._recommender: TopNRecommender | None = None
         # bounded: a long-lived server must not grow one float per request
         self.latencies_s: collections.deque[float] = collections.deque(maxlen=65536)
-        # publish-path stats: swap count and publish -> swap-visible latency
+        # publish-path stats: swap count
         self.swaps = 0
         self.rebinds = 0  # swaps that reused the compiled executables
-        self.publish_to_swap_s: collections.deque[float] = collections.deque(maxlen=4096)
         # publishes the subscriber rejected (e.g. an ensemble smaller than
         # the seen-item index) — kept so a rejection is observable without
         # killing the subscriber thread
@@ -217,7 +216,7 @@ class RecommendFrontend:
             if have_recommender:
                 return False
             raise
-        return self._swap(ensemble, t_publish=None)
+        return self._swap(ensemble)
 
     # ------------------------------------------------------------------
     # publish-path adoption: in-memory ensemble build + atomic swap
@@ -234,9 +233,9 @@ class RecommendFrontend:
         if self.max_samples is not None:
             draws = draws[-self.max_samples:]
         ensemble = PosteriorEnsemble(draws)
-        return self._swap(ensemble, t_publish=snap.t_publish)
+        return self._swap(ensemble)
 
-    def _swap(self, ensemble: PosteriorEnsemble, *, t_publish: float | None) -> bool:
+    def _swap(self, ensemble: PosteriorEnsemble) -> bool:
         """Atomically publish a fully-built successor recommender.
 
         Double-buffered: the old recommender keeps serving until the new one
@@ -271,8 +270,6 @@ class RecommendFrontend:
                 self._recommender = recommender
                 self.swaps += 1
                 self.rebinds += int(rebound)
-                if t_publish is not None:
-                    self.publish_to_swap_s.append(time.perf_counter() - t_publish)
                 self._swap_cond.notify_all()
         return True
 
@@ -441,45 +438,26 @@ class RecommendFrontend:
                    epoch: int) -> list[RecommendResult]:
         if not batch:
             return []
-        topk = max(p.topk for p in batch)
-        warm = [p for p in batch if p.user_id is not None]
-        cold = [p for p in batch if p.user_id is None]
-        out: dict[int, tuple[np.ndarray, np.ndarray]] = {}
+        # one host span per micro-batch, its warm and cold halves inside; a
+        # profiler (jax.profiler.trace) puts them on the device trace's clock
+        with jax.profiler.TraceAnnotation("serve.batch"):
+            topk = max(p.topk for p in batch)
+            warm = [p for p in batch if p.user_id is not None]
+            cold = [p for p in batch if p.user_id is None]
+            out: dict[int, tuple[np.ndarray, np.ndarray]] = {}
 
-        if warm:
-            ids = np.asarray([p.user_id for p in warm], np.int32)
-            vals, idx = rec.recommend(ids, topk, seen=self.seen)
-            for r, p in enumerate(warm):
-                out[p.ticket] = (vals[r], idx[r])
+            if warm:
+                with jax.profiler.TraceAnnotation("serve.warm"):
+                    ids = np.asarray([p.user_id for p in warm], np.int32)
+                    vals, idx = rec.recommend(ids, topk, seen=self.seen)
+                for r, p in enumerate(warm):
+                    out[p.ticket] = (vals[r], idx[r])
 
-        if cold:
-            rows = np.concatenate([
-                np.full(len(p.item_ids), r, np.int32) for r, p in enumerate(cold)
-            ])
-            cols = np.concatenate([p.item_ids for p in cold])
-            vals_r = np.concatenate([p.ratings for p in cold])
-            ratings = SparseRatings(
-                rows=rows, cols=cols, vals=vals_r,
-                shape=(len(cold), rec.ensemble.n_items),
-            )
-            # deterministic fold-in (conditional posterior means): serving
-            # the same ratings twice must return the same recommendations.
-            # The plan cache quantizes the batch's rating-count profile so
-            # the fused (S*B) solve recompiles only on new shape families.
-            u_draws = fold_in(None, ratings, rec.ensemble, sample=False,
-                              plan_cache=self.foldin_cache)  # repro-lint: disable=guarded-field (never rebound; cache is internally locked)
-            # explicit candidate-count pin (topk + batch max degree,
-            # power-of-two quantized) — the same fetch the exclusion lists
-            # imply, but stated independently of them, so the kernel shape
-            # stays pinned even for requests with nothing to exclude
-            hint = topk + max(len(p.item_ids) for p in cold)
-            hint = 1 << (hint - 1).bit_length()
-            vals, idx = rec.recommend_factors(
-                u_draws, topk, exclude=[p.item_ids for p in cold],
-                fetch_hint=hint,
-            )
-            for r, p in enumerate(cold):
-                out[p.ticket] = (vals[r], idx[r])
+            if cold:
+                with jax.profiler.TraceAnnotation("serve.cold"):
+                    vals, idx = self._run_cold(cold, rec, topk)
+                for r, p in enumerate(cold):
+                    out[p.ticket] = (vals[r], idx[r])
 
         t_done = time.perf_counter()
         return [
@@ -492,6 +470,35 @@ class RecommendFrontend:
             )
             for p in batch
         ]
+
+    def _run_cold(self, cold: list[_Pending], rec: TopNRecommender,
+                  topk: int) -> tuple[np.ndarray, np.ndarray]:
+        """Fold in the cold-start requests and score them."""
+        rows = np.concatenate([
+            np.full(len(p.item_ids), r, np.int32) for r, p in enumerate(cold)
+        ])
+        cols = np.concatenate([p.item_ids for p in cold])
+        vals_r = np.concatenate([p.ratings for p in cold])
+        ratings = SparseRatings(
+            rows=rows, cols=cols, vals=vals_r,
+            shape=(len(cold), rec.ensemble.n_items),
+        )
+        # deterministic fold-in (conditional posterior means): serving
+        # the same ratings twice must return the same recommendations.
+        # The plan cache quantizes the batch's rating-count profile so
+        # the fused (S*B) solve recompiles only on new shape families.
+        u_draws = fold_in(None, ratings, rec.ensemble, sample=False,
+                          plan_cache=self.foldin_cache)  # repro-lint: disable=guarded-field (never rebound; cache is internally locked)
+        # explicit candidate-count pin (topk + batch max degree,
+        # power-of-two quantized) — the same fetch the exclusion lists
+        # imply, but stated independently of them, so the kernel shape
+        # stays pinned even for requests with nothing to exclude
+        hint = topk + max(len(p.item_ids) for p in cold)
+        hint = 1 << (hint - 1).bit_length()
+        return rec.recommend_factors(
+            u_draws, topk, exclude=[p.item_ids for p in cold],
+            fetch_hint=hint,
+        )
 
     # ------------------------------------------------------------------
     def latency_percentiles(self) -> dict[str, float]:
