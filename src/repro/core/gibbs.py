@@ -39,7 +39,8 @@ from repro.data.sparse import SparseRatings, csr_from_coo
 #              sampling. The equivalence oracle and benchmark baseline.
 #   einsum     restructured flow (default): same einsum statistics, but
 #              per-segment outputs written once into their seg_item_ids
-#              slots and the batched substitution solver.
+#              slots; solved by the Pallas chol_solve_sample kernel on a
+#              TPU, by the batched substitution solver elsewhere.
 #   kernel     restructured flow through the two-step Pallas kernels
 #              (masked_syrk + chol_solve_sample; interpret mode off-TPU).
 #   fused      restructured flow through the fused gather→syrk→segment-
@@ -312,10 +313,12 @@ def sample_mvn_precision(
     the per-draw key sequence of the original per-sample loop, so fused and
     looped sampling consume identical random bits.
 
-    solver: "subst" (default) — batch-vectorized substitution, the fast
-    path everywhere; "lapack" — the seed 3-triangular-solve formulation
-    (retained for the reference engine); "kernel" — the Pallas
-    chol_solve_sample kernel. All three agree to fp32 rounding.
+    solver: "subst" (default) — XLA's batched Cholesky and the
+    batch-vectorized substitution, the path off the TPU; "lapack" — the
+    seed 3-triangular-solve formulation (retained for the reference
+    engine); "kernel" — the batch-on-lanes Pallas kernel
+    (`kernels.chol_solve`), which the training sweep takes on a TPU. All
+    three compute the same lower factor and agree to fp32 rounding.
     """
     if solver is None:
         solver = "kernel" if use_kernel else "subst"
@@ -416,6 +419,15 @@ def _bucket_update(counterpart, b: DeviceBucket, sample, *, engine,
     return sample(prec, rhs, b.seg_item_ids)
 
 
+def _sweep_solver(engine: str) -> str:
+    """The restructured engines' solve: the batch-on-lanes Pallas kernel on
+    a TPU (and for the kernel engine everywhere); elsewhere the substitution
+    solver, the CPU path and the kernel's equivalence oracle."""
+    if engine == "kernel" or jax.default_backend() == "tpu":
+        return "kernel"
+    return "subst"
+
+
 def update_factors(
     key: jax.Array,
     counterpart: jax.Array,
@@ -440,6 +452,7 @@ def update_factors(
     the bucket plan partitions items, so indices are unique and items with
     no ratings keep the prior draw, as in the seed flow. No (n_items, K, K)
     buffer exists: a full-size user side would need 7.9 GB for it at K=64.
+    The systems are solved by `_sweep_solver(engine)`.
     """
     engine = resolve_engine(engine, use_kernel)
     k = counterpart.shape[-1]
@@ -466,7 +479,7 @@ def update_factors(
             new = chol_subst_solve(
                 jnp.linalg.cholesky(lam), jnp.broadcast_to(lam_mu, (n_items, k)), z
             )
-        solver = "kernel" if engine == "kernel" else "subst"
+        solver = _sweep_solver(engine)
 
         def sample(prec, rhs, item_ids):
             with jax.named_scope("bpmf.prior"):

@@ -1,24 +1,40 @@
-"""Pallas TPU kernel: fused batched Cholesky factor + solve + sample.
+"""Pallas TPU kernel: batched Cholesky factor + solve + sample, batch on lanes.
 
 BPMF never needs the precision inverse (paper Sec 3.1): the sampler needs
 
-    x = Lambda^-1 b + L^-T z           with Lambda = L L^T.
+    x = L^-T (L^-1 b + z)           with Lambda = L L^T,
 
-This kernel fuses, per VMEM-resident batch tile of K x K matrices:
-  1. right-looking Cholesky (column loop, vectorized over the batch tile),
-  2. forward substitution  L y = b,
-  3. one back substitution L^T x = (y + z)  — mean and noise share it.
+the posterior mean Lambda^-1 b plus the noise L^-T z, through one shared
+back substitution.
 
-K is small (64 padded), so a whole (BB, K, K) tile lives in VMEM and each
-stage is a lax.fori_loop over columns — no HBM traffic between the three
-stages, which is the point of fusing them. The loops pick rows and columns
-with iota masks and lane/sublane reductions, not dynamic slices, which
-Mosaic does not lower.
+Layout. One grid step holds `block` systems, batch minor: the precisions
+as (K, K, block) and b, z, x as (K, block), one system per lane. Element
+(i, j) of every system of the tile is then one lane vector, and column j
+of L is the slab `l[j]`, (K, block), rows on the sublanes. Every step of
+the factorization and of the substitutions is a slab multiply-add over
+whole vregs: no masked reductions over a tile, and no half-empty vregs
+where K = 64 is half of the 128 lanes. The wrapper hands the kernel the
+(C, K, K) precisions transposed to (K, K, C) in XLA, which lays out the
+producing fusion batch-minor where it can, and transposes x back.
 
-The batch axis is one flat leading dimension; callers with stacked batches
-— the serving fold-in's (S draws, B users) solve — flatten them into a
-single (S*B) launch through the `kernels.ops.chol_solve_sample` wrapper,
-which also pads the batch to the tile size.
+Factorization: left-looking, with an inner loop over the earlier columns p,
+
+    acc = A[:, j] - sum_{p<j} L[:, p] L[j, p];   L[:, j] = acc / sqrt(acc[j]),
+
+on the rows from j's sublane group down (the rows above j of L[:, j] are
+zero). The group is a static loop, so each slab slice is static; the
+columns within a group and the earlier columns are `fori_loop`s, which
+keeps the traced kernel small: the sweep sends it a dozen batch shapes,
+and each call site traces and lowers it anew. Column j of A is read as
+its row j (A is symmetric).
+Forward substitution L y = b runs column by column (r -= L[:, j] y_j); the
+back substitution L^T x = y + z takes dot products of the columns of L
+with the solved part of x.
+
+A batch that does not fill the last tile is padded inside the kernel with
+identity systems and zero right-hand sides, so no padded copy is made in
+HBM; pad lanes are never written back. `lane_stats()` counts, as each call
+is traced, the systems and the lanes the kernel is sent.
 """
 from __future__ import annotations
 
@@ -27,82 +43,125 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+SUBLANES = 8
+# Systems per grid step, and the inner loop's unroll. At K = 64 the
+# double-buffered (K, K, 256) input and the (K, K, 256) factor take 12 MiB
+# of VMEM.
+BLOCK = 256
+UNROLL = 4
+
+_systems = 0
+_lanes = 0
 
 
-def _chol_solve_kernel(prec_ref, rhs_ref, z_ref, out_ref):
-    a = prec_ref[...].astype(jnp.float32)          # (B, K, K)
-    b = rhs_ref[...].astype(jnp.float32)[:, None, :]   # (B, 1, K)
-    z = z_ref[...].astype(jnp.float32)[:, None, :]     # (B, 1, K)
-    bb, k, _ = a.shape
-    # Column j of a (B, K, K) array is a lane reduction under a lane mask and
-    # comes out along sublanes, (B, K, 1); row j is a sublane reduction and
-    # comes out along lanes, (B, 1, K). Each is used in the orientation it
-    # comes out in, so no loop step slices or relayouts a vector.
-    sub = jax.lax.broadcasted_iota(jnp.int32, (1, k, 1), 1)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, k), 2)
+def lane_stats() -> tuple[int, int]:
+    """(systems, lanes) sent through the kernel by the calls traced so far.
 
-    def col(x, j):
-        return jnp.sum(jnp.where(lane == j, x, 0.0), axis=2, keepdims=True)
+    `chol_solve_sample_pallas` adds a call's batch and that batch padded to
+    whole tiles when the call is traced: once per call site of each
+    compiled program (a call inside a loop body, such as the sweep's row
+    chunks, counts once), once per call when run eagerly. systems / lanes
+    is the share of the kernel's lanes that hold a real system.
+    """
+    return _systems, _lanes
 
-    def row(x, j):
-        return jnp.sum(jnp.where(sub == j, x, 0.0), axis=1, keepdims=True)
 
-    # --- Cholesky, column by column. Invariant: cols >= j of l are zero. ---
-    def chol_col(j, l):
-        s = jnp.sum(l * row(l, j), axis=2, keepdims=True)      # (B, K, 1)
-        c = col(a, j) - s
-        dj = jnp.sqrt(jnp.maximum(row(c, j), 1e-20))           # (B, 1, 1)
-        newcol = jnp.where(sub >= j, c / dj, 0.0)
-        return jnp.where(lane == j, newcol, l)
+def _chol_solve_kernel(prec_ref, rhs_ref, z_ref, out_ref, l_ref, r_ref, x_ref,
+                       *, k: int, n_valid: int, block: int, unroll: int):
+    # lanes past the batch hold identity systems with zero b and z
+    lane = (pl.program_id(0) * block
+            + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1))
+    valid = lane < n_valid                                     # (1, block)
 
-    l = jax.lax.fori_loop(0, k, chol_col, jnp.zeros_like(a))
+    # --- left-looking Cholesky: l[j] = L[:, j], on rows >= lo, the first
+    # row of j's sublane group (static, so every slab slice is static) ---
+    for lo in range(0, k, SUBLANES):
+        rows = jax.lax.broadcasted_iota(jnp.int32, (k - lo, block), 0) + lo
 
-    # --- forward substitution: L y = b (row j of L against solved y) ---
-    def fwd(j, y):
-        lrow = row(l, j)                                       # (B, 1, K)
-        ljj = col(lrow, j)
-        yj = (col(b, j) - jnp.sum(jnp.where(lane < j, lrow, 0.0) * y,
-                                  axis=2, keepdims=True)) / ljj
-        return jnp.where(lane == j, yj, y)
+        def column(j, carry, lo=lo, rows=rows):
+            def step(p, acc):
+                return acc - l_ref[p, lo:, :] * l_ref[p, pl.ds(j, 1), :]
 
-    y = jax.lax.fori_loop(0, k, fwd, jnp.zeros_like(b))
-    y = y + z                                       # mean + noise share L^-T
+            def steps(q, acc):
+                for t in range(unroll):
+                    acc = step(q * unroll + t, acc)
+                return acc
 
-    # --- back substitution: L^T x = y, sweeping rows of L from the last:
-    # x_j = r_j / L[j, j], then r_i -= L[j, i] x_j for every i < j ---
+            a_j = jnp.where(valid, prec_ref[j, lo:, :].astype(jnp.float32),
+                            jnp.where(rows == j, 1.0, 0.0))
+            acc = jax.lax.fori_loop(0, j // unroll, steps, a_j)
+            acc = jax.lax.fori_loop(j // unroll * unroll, j, step, acc)
+            l_ref[j, lo:, :] = acc
+            d = l_ref[j, pl.ds(j, 1), :]               # A_jj - sum_p L_jp^2
+            l_ref[j, lo:, :] = jnp.where(rows >= j, acc * (1.0 / jnp.sqrt(d)),
+                                         0.0)
+            if lo:
+                l_ref[j, :lo, :] = jnp.zeros((lo, block), jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(lo, min(lo + SUBLANES, k), column, 0)
+
+    # --- forward substitution L y = b: r holds b less the solved part;
+    # y_j = r_j / L[j, j], then r -= L[:, j] y_j ---
+    r_ref[...] = jnp.where(valid, rhs_ref[...].astype(jnp.float32), 0.0)
+
+    def fwd(j, carry):
+        yj = r_ref[pl.ds(j, 1), :] / l_ref[j, pl.ds(j, 1), :]
+        r_ref[...] = r_ref[...] - l_ref[j] * yj
+        x_ref[pl.ds(j, 1), :] = yj
+        return carry
+
+    jax.lax.fori_loop(0, k, fwd, 0)
+
+    # --- back substitution L^T x = y + z, from the last row up:
+    # x_j = (c_j - L[:, j] . x) / L[j, j], unsolved entries of x still 0 ---
+    r_ref[...] = x_ref[...] + jnp.where(valid, z_ref[...].astype(jnp.float32),
+                                        0.0)
+    x_ref[...] = jnp.zeros((k, block), jnp.float32)
+
     def bwd(t, carry):
-        x, r = carry
         j = k - 1 - t
-        lrow = row(l, j)
-        xj = col(r, j) / col(lrow, j)
-        return jnp.where(lane == j, xj, x), r - lrow * xj
+        dot = jnp.sum(l_ref[j] * x_ref[...], axis=0, keepdims=True)
+        x_ref[pl.ds(j, 1), :] = ((r_ref[pl.ds(j, 1), :] - dot)
+                                 / l_ref[j, pl.ds(j, 1), :])
+        return carry
 
-    x, _ = jax.lax.fori_loop(0, k, bwd, (jnp.zeros_like(b), y))
-    out_ref[...] = x[:, 0, :]
+    jax.lax.fori_loop(0, k, bwd, 0)
+    out_ref[...] = x_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
-def chol_solve_sample_pallas(
-    prec: jax.Array,
-    rhs: jax.Array,
-    z: jax.Array,
-    *,
-    block_b: int = 16,
-    interpret: bool = False,
-) -> jax.Array:
-    """prec: (B, K, K), rhs/z: (B, K) -> x (B, K). B % block_b == 0."""
-    bsz, k, _ = prec.shape
-    assert bsz % block_b == 0, (bsz, block_b)
-    grid = (bsz // block_b,)
-    return pl.pallas_call(
-        _chol_solve_kernel,
-        grid=grid,
+@functools.partial(jax.jit, static_argnames=("block", "unroll", "interpret"))
+def _solve(prec, rhs, z, *, block: int, unroll: int, interpret: bool):
+    c, k, _ = prec.shape
+    kernel = functools.partial(_chol_solve_kernel, k=k, n_valid=c,
+                               block=block, unroll=unroll)
+    x = pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(c, block),),
         in_specs=[
-            pl.BlockSpec((block_b, k, k), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_b, k), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, k), lambda i: (i, 0)),
+            pl.BlockSpec((k, k, block), lambda i: (0, 0, i)),
+            pl.BlockSpec((k, block), lambda i: (0, i)),
+            pl.BlockSpec((k, block), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((block_b, k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bsz, k), jnp.float32),
+        out_specs=pl.BlockSpec((k, block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((k, c), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((k, k, block), jnp.float32),
+                        pltpu.VMEM((k, block), jnp.float32),
+                        pltpu.VMEM((k, block), jnp.float32)],
         interpret=interpret,
-    )(prec, rhs, z)
+        name="chol_solve_sample",
+    )(prec.transpose(1, 2, 0), rhs.T, z.T)
+    return x.T
+
+
+def chol_solve_sample_pallas(prec: jax.Array, rhs: jax.Array, z: jax.Array, *,
+                             interpret: bool = False) -> jax.Array:
+    """prec: (C, K, K) symmetric positive definite, rhs/z: (C, K) -> x (C, K),
+    for any C >= 1."""
+    global _systems, _lanes
+    c = prec.shape[0]
+    _systems += c
+    _lanes += -(-c // BLOCK) * BLOCK
+    return _solve(prec, rhs, z, block=BLOCK, unroll=UNROLL, interpret=interpret)

@@ -64,36 +64,23 @@ def masked_syrk(vm: jax.Array, rv: jax.Array, *, interpret: bool | None = None):
 
 def chol_solve_sample(prec: jax.Array, rhs: jax.Array, z: jax.Array,
                       *, interpret: bool | None = None):
-    """Batched x = Lambda^-1 rhs + L^-T z. Pads the batch to the tile size.
+    """Batched x = L^-T (L^-1 rhs + z) with Lambda = L L^T, through the
+    batch-on-lanes Pallas kernel (`kernels.chol_solve`).
 
     Any leading axes — (B,), or the fold-in's stacked (S, B) — are flattened
     into one kernel batch: an (S, B, K, K) precision stack becomes a single
-    (S*B) launch, which is the fused serving solve. The K axis is NOT padded
-    (a zero-padded precision matrix is singular); callers keep K at an
-    MXU-friendly size (BPMF uses K=64).
+    (S*B) launch. A batch that does not fill the kernel's last tile is
+    padded inside the kernel with identity systems and zero right-hand
+    sides, so no padded copy is made in HBM. The K axis is NOT padded (a
+    zero-padded precision matrix is singular); BPMF uses K=64.
     """
     interpret = (not _on_tpu()) if interpret is None else interpret
-    if prec.ndim > 3:
-        lead = prec.shape[:-2]
-        out = chol_solve_sample(
-            prec.reshape((-1,) + prec.shape[-2:]),
-            rhs.reshape((-1, rhs.shape[-1])),
-            z.reshape((-1, z.shape[-1])),
-            interpret=interpret,
-        )
-        return out.reshape(lead + out.shape[1:])
-    bsz = prec.shape[0]
-    # always tile: an unaligned batch is padded with identity systems below
-    # rather than degrading to one-row tiles
-    block_b = 16 if bsz >= 16 else 8
-    if bsz % block_b:
-        pad = (-bsz) % block_b
-        eye = jnp.broadcast_to(jnp.eye(prec.shape[-1], dtype=prec.dtype), (pad,) + prec.shape[1:])
-        prec = jnp.concatenate([prec, eye], 0)
-        rhs = jnp.concatenate([rhs, jnp.zeros((pad, rhs.shape[1]), rhs.dtype)], 0)
-        z = jnp.concatenate([z, jnp.zeros((pad, z.shape[1]), z.dtype)], 0)
-    out = chol_solve_sample_pallas(prec, rhs, z, block_b=block_b, interpret=interpret)
-    return out[:bsz]
+    k = prec.shape[-1]
+    out = chol_solve_sample_pallas(
+        prec.reshape(-1, k, k), rhs.reshape(-1, k), z.reshape(-1, k),
+        interpret=interpret,
+    )
+    return out.reshape(rhs.shape)
 
 
 def flash_attention(

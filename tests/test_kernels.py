@@ -4,7 +4,8 @@ import jax.numpy as jnp
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.kernels import ops, ref
+from repro.core.gibbs import sample_mvn_precision
+from repro.kernels import chol_solve, ops, ref
 
 
 # ---------------------------------------------------------------------------
@@ -44,7 +45,11 @@ def test_syrk_property(r, w, k, seed):
 # ---------------------------------------------------------------------------
 # fused cholesky-solve-sample
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("b,k", [(16, 16), (32, 64), (7, 24), (1, 8), (64, 32)])
+@pytest.mark.parametrize("b,k", [
+    (16, 16), (32, 64), (7, 24), (1, 8), (64, 32),
+    # batches that do not fill the kernel's tile, at the sweep's K
+    (1, 64), (7, 64), (129, 64), (300, 64),
+])
 def test_chol_solve_shapes(b, k):
     rng = np.random.default_rng(b + k)
     a = rng.normal(size=(b, k, k))
@@ -54,6 +59,9 @@ def test_chol_solve_shapes(b, k):
     x1 = ops.chol_solve_sample(prec, rhs, z)
     x2 = ref.chol_solve_sample_ref(prec, rhs, z)
     np.testing.assert_allclose(x1, x2, rtol=2e-3, atol=2e-3)
+    # the sweep's off-TPU solver, on the same z
+    x3 = sample_mvn_precision(None, prec, rhs, z=z, solver="subst")
+    np.testing.assert_allclose(x1, x3, rtol=2e-3, atol=2e-3)
 
 
 def test_chol_solve_zero_noise_solves_system():
@@ -66,6 +74,42 @@ def test_chol_solve_zero_noise_solves_system():
     x = ops.chol_solve_sample(prec, rhs, jnp.zeros_like(rhs))
     recon = jnp.einsum("bij,bj->bi", prec, x)
     np.testing.assert_allclose(recon, rhs, rtol=3e-3, atol=3e-3)
+
+
+def test_chol_solve_low_rank_systems_match_float64():
+    """The sweep's typical system: a compound with three ratings, the
+    prior's diagonal plus a rank-3 term, solved against float64."""
+    rng = np.random.default_rng(11)
+    b, k = 256, 64
+    v = rng.normal(size=(b, 3, k))
+    prec = np.einsum("bwk,bwl->bkl", v, v) + 0.7 * np.eye(k)
+    rhs = rng.normal(size=(b, k))
+    z = rng.normal(size=(b, k))
+    chol = np.linalg.cholesky(prec)
+    want = np.stack([np.linalg.solve(chol[i].T, np.linalg.solve(chol[i], rhs[i]) + z[i])
+                     for i in range(b)])
+    got = np.asarray(ops.chol_solve_sample(*(jnp.asarray(x, jnp.float32)
+                                             for x in (prec, rhs, z))))
+    assert np.linalg.norm(got - want) / np.linalg.norm(want) < 1e-5
+
+
+def test_chol_solve_lane_stats_count_systems_and_tiles():
+    """lane_stats() adds each call's systems and its lanes, the batch padded
+    to whole tiles; a stacked (S, B) batch is one launch."""
+    rng = np.random.default_rng(3)
+    k = 8
+    prec = jnp.broadcast_to(2.0 * jnp.eye(k), (2, 150, k, k))   # L = sqrt(2) I
+    rhs = jnp.asarray(rng.normal(size=(2, 150, k)), jnp.float32)
+    z = jnp.asarray(rng.normal(size=(2, 150, k)), jnp.float32)
+    before = chol_solve.lane_stats()
+    x1 = ops.chol_solve_sample(prec[0], rhs[0], z[0])
+    x2 = ops.chol_solve_sample(prec, rhs, z)
+    systems, lanes = (a - b for a, b in zip(chol_solve.lane_stats(), before))
+    tile = chol_solve.BLOCK
+    assert (systems, lanes) == (150 + 300, tile * (-(-150 // tile) + -(-300 // tile)))
+    want = rhs / 2 + z / np.sqrt(2.0)
+    np.testing.assert_allclose(x1, want[0], rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(x2, want, rtol=1e-6, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

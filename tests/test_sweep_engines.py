@@ -131,6 +131,36 @@ def test_gibbs_sweeps_identical_across_engines(small_data, engine):
                                atol=2e-3, rtol=2e-3)
 
 
+def test_sweep_kernel_solve_matches_subst(small_data, monkeypatch):
+    """On a TPU the restructured engines solve through the batch-on-lanes
+    Pallas kernel (`gibbs._sweep_solver`); here it runs in interpret mode.
+    A sweep draws the same factors as with the substitution solver, to f32
+    rounding, on a plan whose widest user bucket splits users across rows,
+    and lane_stats() counts every system the plan holds."""
+    import repro.core.gibbs as gibbs
+    from repro.kernels import chol_solve
+
+    train, test = small_data
+    kw = dict(k=16, alpha=10.0, widths=(8, 32))
+    subst = GibbsSampler(train, test, **kw)
+    assert gibbs._sweep_solver(subst.engine) == "subst"
+    st_s = subst.sweep(subst.init(0))
+
+    monkeypatch.setattr(gibbs, "_sweep_solver", lambda engine: "kernel")
+    kern = GibbsSampler(train, test, **kw)
+    buckets = kern.item_buckets + kern.user_buckets
+    assert not all(b.identity_segments for b in buckets)
+    before = chol_solve.lane_stats()
+    st_k = kern.sweep(kern.init(0))
+    systems, lanes = (a - b for a, b in zip(chol_solve.lane_stats(), before))
+    assert systems == sum(b.n_segments for b in buckets)
+    tile = chol_solve.BLOCK
+    assert lanes == sum(-(-b.n_segments // tile) * tile for b in buckets)
+    for got, want in ((st_k.u, st_s.u), (st_k.v, st_s.v)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+
+
 def test_bf16_gather_engine_close_but_looser(small_data):
     train, _ = small_data
     f32 = GibbsSampler(train, None, k=16, alpha=10.0, widths=(8, 32),

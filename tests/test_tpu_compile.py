@@ -83,10 +83,14 @@ def test_topn_compiles_for_v5e(one_chip, n_items, topk, block_n):
     assert "tpu_custom_call" in text
 
 
-def test_chol_solve_sample_compiles_for_v5e(one_chip):
-    b = 4096
+@pytest.mark.parametrize("b", [
+    4096,
+    16_384,    # one row chunk of the training sweep (gibbs.chunk_rows at K=64)
+    16 * 32,   # a fold-in: 16 draws x 32 cold-start users
+])
+def test_chol_solve_sample_compiles_for_v5e(one_chip, b):
     text = _compile_text(
-        one_chip, chol_solve_sample_pallas,
+        one_chip, jax.jit(chol_solve_sample_pallas),
         ((b, K, K), jnp.float32), ((b, K), jnp.float32), ((b, K), jnp.float32),
     )
     assert "tpu_custom_call" in text
