@@ -18,7 +18,10 @@ generated from a fixed seed; the rank is K=64. On one chip:
 
 With --chips 4 it runs only `DistributedBPMF` in ring, allgather and async
 modes on a 4-device mesh, and the 4-host `ClusterCoordinator` against the
-single-host `TopNRecommender`.
+single-host `TopNRecommender`. The distributed sweep solves its systems as
+the single-chip sweep does (`gibbs._sweep_solver`: the batch-on-lanes Pallas
+kernel on a TPU), so async's first-sweep v must still be bit-equal to
+ring's, and ring and allgather must agree within their tolerance.
 
 The lowered sweep and top-N step must contain `tpu_custom_call` (no kernel
 runs interpreted). Any mismatch, or a first device that is not a TPU, exits

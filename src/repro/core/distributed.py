@@ -43,7 +43,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.gibbs import chol_subst_solve, chunk_rows
+from repro.core.gibbs import (
+    _sweep_solver,
+    chunk_rows,
+    sample_mvn_precision,
+    scopes_in_cache_key,
+)
 from repro.core.hyper import (
     HyperParams,
     NWPrior,
@@ -107,24 +112,34 @@ def _accumulate_block(prec, rhs, counter_blk, idx, val, msk, seg, seg_dense,
     seg_map. Slot n_loc collects the padding and is dropped. Adding into the
     accumulators in place keeps one (n_loc, K, K) buffer live, not two.
     """
-    if engine == "fused":
-        from repro.kernels import ops as kops
+    with jax.named_scope("bpmf.stats"):
+        if engine == "fused":
+            from repro.kernels import ops as kops
 
-        prec_seg, rhs_seg = kops.gather_syrk_seg(
-            idx, val, msk, seg_dense, idx.shape[0], counter_blk
+            prec_seg, rhs_seg = kops.gather_syrk_seg(
+                idx, val, msk, seg_dense, idx.shape[0], counter_blk
+            )
+            return (prec.at[seg_map].add(prec_seg, mode="drop"),
+                    rhs.at[seg_map].add(rhs_seg, mode="drop"))
+        vg = counter_blk[idx]                            # (R, W, K)
+        vm = vg * msk[..., None]
+        prec_rows = jnp.einsum("rwk,rwl->rkl", vm, vm,
+                               preferred_element_type=jnp.float32)
+        rhs_rows = jnp.einsum("rwk,rw->rk", vm, val * msk)
+        return (prec.at[seg].add(prec_rows, mode="drop"),
+                rhs.at[seg].add(rhs_rows, mode="drop"))
+
+
+def _forward(blk, n_shards):
+    """Send the held block one step round the ring."""
+    with jax.named_scope("bpmf.exchange"):
+        return jax.lax.ppermute(
+            blk, AXIS, [(i, (i + 1) % n_shards) for i in range(n_shards)]
         )
-        return (prec.at[seg_map].add(prec_seg, mode="drop"),
-                rhs.at[seg_map].add(rhs_seg, mode="drop"))
-    vg = counter_blk[idx]                            # (R, W, K)
-    vm = vg * msk[..., None]
-    prec_rows = jnp.einsum("rwk,rwl->rkl", vm, vm, preferred_element_type=jnp.float32)
-    rhs_rows = jnp.einsum("rwk,rw->rk", vm, val * msk)
-    return (prec.at[seg].add(prec_rows, mode="drop"),
-            rhs.at[seg].add(rhs_rows, mode="drop"))
 
 
 def _phase_ring(key, counter_blk, plans, item_ids, hyper, alpha, n_shards,
-                engine):
+                engine, solver):
     """One ring half-sweep: resample local items given sharded counterpart.
 
     plans: (P, R, W) arrays (this shard's slice of the grid plan) keyed by
@@ -145,44 +160,49 @@ def _phase_ring(key, counter_blk, plans, item_ids, hyper, alpha, n_shards,
             take(seg_all), take(segd_all), take(segm_all), engine=engine,
         )
         # forward the block; independent of this step's accumulate -> overlap
-        blk = jax.lax.ppermute(
-            blk, AXIS, [(i, (i + 1) % n_shards) for i in range(n_shards)]
-        )
-        return (blk, prec, rhs), None
+        return (_forward(blk, n_shards), prec, rhs), None
 
     prec0 = jnp.zeros((n_loc, k, k), jnp.float32)
     rhs0 = jnp.zeros((n_loc, k), jnp.float32)
     (blk, prec, rhs), _ = jax.lax.scan(
         step, (counter_blk, prec0, rhs0), jnp.arange(n_shards)
     )
-    return _finish_phase(key, prec, rhs, item_ids, hyper, alpha)
+    return _finish_phase(key, prec, rhs, item_ids, hyper, alpha, solver)
 
 
-def _finish_phase(key, prec, rhs, item_ids, hyper, alpha):
+def _finish_phase(key, prec, rhs, item_ids, hyper, alpha, solver):
     """Raw accumulated stats -> posterior draw for this shard's items.
 
     Solved in fixed-size item chunks (the last one clamped to end at n_loc,
     re-solving a few items identically) so the Cholesky factors of a full
-    ChEMBL shard never coexist with its statistics.
+    ChEMBL shard never coexist with its statistics. Each chunk is solved as
+    the single-chip sweep solves a bucket: `solver` is
+    `gibbs._sweep_solver`'s choice, the batch-on-lanes Pallas kernel on a
+    TPU and the substitution solver elsewhere.
     """
     n, k = rhs.shape
     c = min(n, chunk_rows(k, k))
-    z = _per_item_noise(key, item_ids, k)
-    lam, lam_mu = hyper.lam[None], (hyper.lam @ hyper.mu)[None]
+    with jax.named_scope("bpmf.prior"):
+        z = _per_item_noise(key, item_ids, k)
+        lam, lam_mu = hyper.lam[None], (hyper.lam @ hyper.mu)[None]
 
     def solve_chunk(i, new):
         start = jnp.minimum(i * c, n - c)
         part = lambda a: jax.lax.dynamic_slice_in_dim(a, start, c)
-        x = _chol_sample(lam + alpha * part(prec), lam_mu + alpha * part(rhs),
-                         part(z))
-        return jax.lax.dynamic_update_slice_in_dim(new, x, start, 0)
+        with jax.named_scope("bpmf.prior"):
+            prec_c = lam + alpha * part(prec)
+            rhs_c = lam_mu + alpha * part(rhs)
+        x = sample_mvn_precision(None, prec_c, rhs_c, z=part(z), solver=solver)
+        with jax.named_scope("bpmf.prior"):
+            return jax.lax.dynamic_update_slice_in_dim(new, x, start, 0)
 
     new = jax.lax.fori_loop(0, -(-n // c), solve_chunk, jnp.zeros_like(rhs))
-    return jnp.where(item_ids[:, None] >= 0, new, 0.0)
+    with jax.named_scope("bpmf.prior"):
+        return jnp.where(item_ids[:, None] >= 0, new, 0.0)
 
 
 def _phase_ring_async(k_v, k_u, u_blk, v_blk, v_plans, u_plans, v_ids, u_ids,
-                      hyper_v, hyper_u, alpha, n_shards, engine):
+                      hyper_v, hyper_u, alpha, n_shards, engine, solver):
     """Both Gibbs phases fused into ONE stale-tolerant ring scan.
 
     Each step first issues the ppermutes that deliver step s+1's blocks —
@@ -201,15 +221,14 @@ def _phase_ring_async(k_v, k_u, u_blk, v_blk, v_plans, u_plans, v_ids, u_ids,
     n_u = u_ids.shape[0]
     k = u_blk.shape[-1]
     pid = jax.lax.axis_index(AXIS)
-    fwd = [(i, (i + 1) % n_shards) for i in range(n_shards)]
 
     def step(carry, s):
         ub, vb, pv, rv, pu, ru = carry
         src = jnp.mod(pid - s, n_shards)
         take = lambda plans: tuple(jnp.take(a, src, axis=0) for a in plans)
         # next blocks, issued before either accumulate touches the held ones
-        ub_next = jax.lax.ppermute(ub, AXIS, fwd)
-        vb_next = jax.lax.ppermute(vb, AXIS, fwd)
+        ub_next = _forward(ub, n_shards)
+        vb_next = _forward(vb, n_shards)
         pv, rv = _accumulate_block(pv, rv, ub, *take(v_plans), engine=engine)
         pu, ru = _accumulate_block(pu, ru, vb, *take(u_plans), engine=engine)
         return (ub_next, vb_next, pv, rv, pu, ru), None
@@ -220,15 +239,16 @@ def _phase_ring_async(k_v, k_u, u_blk, v_blk, v_plans, u_plans, v_ids, u_ids,
         jnp.zeros((n_u, k, k), jnp.float32), jnp.zeros((n_u, k), jnp.float32),
     )
     (_, _, pv, rv, pu, ru), _ = jax.lax.scan(step, init, jnp.arange(n_shards))
-    v_new = _finish_phase(k_v, pv, rv, v_ids, hyper_v, alpha)
-    u_new = _finish_phase(k_u, pu, ru, u_ids, hyper_u, alpha)
+    v_new = _finish_phase(k_v, pv, rv, v_ids, hyper_v, alpha, solver)
+    u_new = _finish_phase(k_u, pu, ru, u_ids, hyper_u, alpha, solver)
     return v_new, u_new
 
 
 def _phase_allgather(key, counter_blk, plan_full, item_ids, hyper, alpha,
-                     engine):
+                     engine, solver):
     """Sync baseline: gather the whole counterpart, then sweep locally."""
-    full = jax.lax.all_gather(counter_blk, AXIS)      # (P, n_loc, K)
+    with jax.named_scope("bpmf.exchange"):
+        full = jax.lax.all_gather(counter_blk, AXIS)      # (P, n_loc, K)
     full = full.reshape(-1, full.shape[-1])
     n_loc = item_ids.shape[0]
     k = full.shape[-1]
@@ -236,23 +256,27 @@ def _phase_allgather(key, counter_blk, plan_full, item_ids, hyper, alpha,
         jnp.zeros((n_loc, k, k), jnp.float32), jnp.zeros((n_loc, k), jnp.float32),
         full, *plan_full, engine=engine,
     )
-    return _finish_phase(key, prec, rhs, item_ids, hyper, alpha)
-
-
-def _chol_sample(prec, rhs, z):
-    # batch-vectorized substitution (core.gibbs): XLA's batched triangular
-    # solve dispatches per batch element on CPU and dominated the sweep
-    return chol_subst_solve(jnp.linalg.cholesky(prec), rhs, z)
+    return _finish_phase(key, prec, rhs, item_ids, hyper, alpha, solver)
 
 
 def _stats(x, valid):
-    xm = jnp.where(valid[:, None], x, 0.0)
-    sum_x = jax.lax.psum(xm.sum(0), AXIS)
-    sum_xxt = jax.lax.psum(
-        jnp.einsum("nk,nl->kl", xm, xm, preferred_element_type=jnp.float32), AXIS
-    )
-    n = jax.lax.psum(valid.sum(), AXIS)
-    return sum_x, sum_xxt, n
+    """Sufficient statistics of the valid rows of every shard's factors:
+    local sums under `bpmf.hyper`, their sums over the mesh under
+    `bpmf.exchange`."""
+    with jax.named_scope("bpmf.hyper"):
+        xm = jnp.where(valid[:, None], x, 0.0)
+        local = (xm.sum(0),
+                 jnp.einsum("nk,nl->kl", xm, xm, preferred_element_type=jnp.float32),
+                 valid.sum())
+    with jax.named_scope("bpmf.exchange"):
+        return tuple(jax.lax.psum(a, AXIS) for a in local)
+
+
+def _hyper_draw(key, x, valid, prior):
+    """Normal-Wishart draw from the factors' statistics over the mesh."""
+    stats = _stats(x, valid)
+    with jax.named_scope("bpmf.hyper"):
+        return sample_normal_wishart(key, *stats, prior)
 
 
 def make_sweep(mesh: Mesh, mode: str, alpha: float, prior: NWPrior,
@@ -262,13 +286,15 @@ def make_sweep(mesh: Mesh, mode: str, alpha: float, prior: NWPrior,
     Standalone so the production-mesh dry-run can lower it against
     ShapeDtypeStruct plans without building a real plan. `engine` picks the
     per-block stats path (DIST_ENGINES); plans are 6-tuples
-    (idx, val, msk, seg, seg_dense, seg_map).
+    (idx, val, msk, seg, seg_dense, seg_map). The systems are solved by
+    `gibbs._sweep_solver(engine)`, as in the single-chip sweep.
     """
     if engine not in DIST_ENGINES:
         raise ValueError(f"engine must be one of {DIST_ENGINES}, got {engine!r}")
     if mode not in DIST_MODES:
         raise ValueError(f"mode must be one of {DIST_MODES}, got {mode!r}")
     n_shards = mesh.shape[AXIS]
+    solver = _sweep_solver(engine)
 
     def sweep(state: DistState, u_plans, v_plans, u_ids, v_ids):
         key, k_hv, k_v, k_hu, k_u = jax.random.split(state.key, 5)
@@ -281,14 +307,12 @@ def make_sweep(mesh: Mesh, mode: str, alpha: float, prior: NWPrior,
         # both hyper draws read the PREVIOUS sweep's factors in every mode
         # (sync modes too: su below uses state.u, not u_new) — so async can
         # hoist them above its fused scan without changing a single bit
-        sv = _stats(state.v[0], v_ids >= 0)
-        hyper_v = sample_normal_wishart(k_hv, *sv, prior)
+        hyper_v = _hyper_draw(k_hv, state.v[0], v_ids >= 0, prior)
         if mode == "async":
-            su = _stats(state.u[0], u_ids >= 0)
-            hyper_u = sample_normal_wishart(k_hu, *su, prior)
+            hyper_u = _hyper_draw(k_hu, state.u[0], u_ids >= 0, prior)
             v_new, u_new = _phase_ring_async(
                 k_v, k_u, state.u[0], state.v[0], v_plans, u_plans,
-                v_ids, u_ids, hyper_v, hyper_u, alpha, n_shards, engine,
+                v_ids, u_ids, hyper_v, hyper_u, alpha, n_shards, engine, solver,
             )
             return DistState(
                 u=u_new[None], v=v_new[None],
@@ -300,19 +324,18 @@ def make_sweep(mesh: Mesh, mode: str, alpha: float, prior: NWPrior,
         # movies phase
         if mode == "ring":
             v_new = _phase_ring(k_v, state.u[0], v_plans, v_ids, hyper_v,
-                                alpha, n_shards, engine)
+                                alpha, n_shards, engine, solver)
         else:
             v_new = _phase_allgather(k_v, state.u[0], v_plans, v_ids, hyper_v,
-                                     alpha, engine)
+                                     alpha, engine, solver)
 
-        su = _stats(state.u[0], u_ids >= 0)
-        hyper_u = sample_normal_wishart(k_hu, *su, prior)
+        hyper_u = _hyper_draw(k_hu, state.u[0], u_ids >= 0, prior)
         if mode == "ring":
             u_new = _phase_ring(k_u, v_new, u_plans, u_ids, hyper_u,
-                                alpha, n_shards, engine)
+                                alpha, n_shards, engine, solver)
         else:
             u_new = _phase_allgather(k_u, v_new, u_plans, u_ids, hyper_u,
-                                     alpha, engine)
+                                     alpha, engine, solver)
 
         return DistState(
             u=u_new[None], v=v_new[None], hyper_u=hyper_u, hyper_v=hyper_v,
@@ -375,7 +398,20 @@ class DistributedBPMF:
             centered.transpose(), self.v_part, self.u_part, width=width
         )
         self.prior = default_prior(k)
+        self._plan_stats = {side: {
+            "rated": plan.mask.sum(axis=(1, 2, 3)).astype(np.int64).tolist(),
+            "sent": [int(np.prod(plan.mask.shape[1:]))] * p,
+        } for side, plan in (("u", self.u_plan), ("v", self.v_plan))}
         self._sweep = self._build_sweep()
+
+    def plan_stats(self) -> dict:
+        """Per shard, the rated slots and the slots sent to the statistics
+        pass of the user ("u") and item ("v") grid plans:
+        `{"u": {"rated": [...], "sent": [...]}, "v": ...}`, one entry per
+        shard. Counted on the host when the plans are built; rated / sent is
+        the plans' fill."""
+        return {side: {k: list(v) for k, v in d.items()}
+                for side, d in self._plan_stats.items()}
 
     # ------------------------------------------------------------------
     def _device_plans(self, plan: GridPlan):
@@ -473,7 +509,8 @@ class DistributedBPMF:
         )
 
     def sweep(self, state: DistState) -> DistState:
-        return self._sweep(state, *self._plan_args)
+        with scopes_in_cache_key():
+            return self._sweep(state, *self._plan_args)
 
     def gather_factors(self, state: DistState, *, coupled: bool = True):
         """(M, K), (N, K) in global entity order (host-side, for eval).

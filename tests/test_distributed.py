@@ -255,7 +255,8 @@ def test_finish_phase_chunked_solve_matches_whole(monkeypatch):
 
     def finish():  # the same key both times: the draws must match exactly
         return np.asarray(_finish_phase(jax.random.PRNGKey(3), prec, rhs, ids,
-                                        init_hyper(k), 2.0))
+                                        init_hyper(k), 2.0,
+                                        gibbs._sweep_solver("einsum")))
 
     whole = finish()
     monkeypatch.setattr(gibbs, "CHUNK_BYTES", 4 * k * k * 8)   # 8-item chunks
@@ -263,3 +264,145 @@ def test_finish_phase_chunked_solve_matches_whole(monkeypatch):
     chunked = finish()
     np.testing.assert_allclose(chunked, whole, rtol=1e-5, atol=1e-5)
     assert np.isfinite(whole).all() and not whole[33:].any()
+
+
+def test_finish_phase_kernel_route_matches_subst():
+    """The distributed sweep solves through the single-chip sweep's solver
+    choice: `_sweep_solver`'s kernel route (the Pallas kernel, interpreted
+    off the TPU) and its substitution route give the same draws on one
+    chunk, pad slots zero."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.distributed import _finish_phase
+    from repro.core.gibbs import _sweep_solver
+    from repro.core.hyper import HyperParams
+
+    rng = np.random.default_rng(1)
+    n, k = 45, 16
+    a = rng.normal(size=(n, k, 2 * k)).astype(np.float32)
+    prec = jnp.asarray(a @ a.transpose(0, 2, 1))
+    rhs = jnp.asarray(rng.normal(size=(n, k)).astype(np.float32))
+    ids = jnp.asarray(np.where(np.arange(n) < 41, np.arange(n) * 3, -1), jnp.int32)
+    b = rng.normal(size=(k, k)).astype(np.float32)
+    hyper = HyperParams(mu=jnp.asarray(rng.normal(size=k).astype(np.float32)),
+                        lam=jnp.asarray(b @ b.T / k + np.eye(k, dtype=np.float32)))
+    assert (_sweep_solver("kernel"), _sweep_solver("einsum")) == ("kernel", "subst")
+
+    def finish(solver):
+        return np.asarray(_finish_phase(jax.random.PRNGKey(5), prec, rhs, ids,
+                                        hyper, 2.5, _sweep_solver(solver)))
+
+    kernel, subst = finish("kernel"), finish("einsum")
+    np.testing.assert_allclose(kernel, subst, rtol=2e-4, atol=2e-5)
+    assert np.isfinite(kernel).all() and not kernel[41:].any()
+
+
+# Float64 plain reference of a distributed sweep, run inside the test's
+# subprocess: dense posteriors per entity, the Normal-Wishart draw from the
+# previous sweep's factors, noise folded from each entity's global id, and
+# for `async` a user half conditioned on the previous sweep's v.
+_REFERENCE = """
+import jax
+import numpy as np
+
+def nw(key, x):
+    n, k = x.shape
+    kw, km = jax.random.split(key)
+    kn, kc = jax.random.split(kw)
+    dfs = np.float32(k + n) - np.arange(k, dtype=np.float32)
+    chi2 = 2.0 * np.asarray(jax.random.gamma(kc, dfs / 2.0, dtype=np.float32), np.float64)
+    normal = np.asarray(jax.random.normal(kn, (k, k), np.float32), np.float64)
+    z = np.asarray(jax.random.normal(km, (k,), np.float32), np.float64)
+    xbar = x.mean(0)
+    beta = 2.0 + n
+    w_inv = np.eye(k) + x.T @ x - n * np.outer(xbar, xbar) + (2.0 * n / beta) * np.outer(xbar, xbar)
+    w = np.linalg.inv(w_inv)
+    la = np.linalg.cholesky(0.5 * (w + w.T)) @ (np.tril(normal, -1) + np.diag(np.sqrt(chi2)))
+    lam = la @ la.T
+    chol = np.linalg.cholesky(beta * lam + 1e-6 * np.eye(k))
+    return n * xbar / beta + np.linalg.solve(chol.T, z), lam
+
+def draws(key, rows, cols, vals, n_rows, counter, mu, lam, alpha):
+    k = counter.shape[1]
+    out = np.empty((n_rows, k))
+    for i in range(n_rows):
+        sel = rows == i
+        c = counter[cols[sel]]
+        chol = np.linalg.cholesky(lam + alpha * c.T @ c)
+        z = np.asarray(jax.random.normal(jax.random.fold_in(key, i), (k,), np.float32), np.float64)
+        y = np.linalg.solve(chol, lam @ mu + alpha * c.T @ vals[sel]) + z
+        out[i] = np.linalg.solve(chol.T, y)
+    return out
+
+def sweep(key, u, v, rows, cols, vals, alpha, stale):
+    key, k_hv, k_v, k_hu, k_u = jax.random.split(key, 5)
+    m, n = u.shape[0], v.shape[0]
+    v_new = draws(k_v, cols, rows, vals, n, u, *nw(k_hv, v), alpha)
+    u_new = draws(k_u, rows, cols, vals, m, v if stale else v_new, *nw(k_hu, u), alpha)
+    return key, u_new, v_new
+"""
+
+
+@pytest.mark.parametrize("mode", ["ring", "async"])
+def test_two_sweeps_match_float64_reference(mode):
+    """Two sweeps on a 4-device mesh against a float64 reference of the
+    sampler's conditionals and random streams. Each sweep starts the
+    reference from the program's previous state; the u half of `async`
+    reads the previous sweep's v, and its state carries that v as
+    `v_eval`."""
+    out = run_sub(_REFERENCE + f"""
+import json
+from repro.data import synthetic_lowrank
+from repro.core.distributed import DistributedBPMF
+
+ratings, _, _ = synthetic_lowrank(70, 45, k_true=3, nnz=700, noise=0.3, seed=8)
+d = DistributedBPMF(ratings, k=6, alpha=3.0, mode={mode!r}, width="auto")
+rows, cols = ratings.rows, ratings.cols
+vals = ratings.vals.astype(np.float64) - ratings.vals.astype(np.float64).mean()
+state = d.init(11)
+key = jax.random.split(jax.random.PRNGKey(11), 3)[2]
+gaps = []
+for _ in range(2):
+    u, v = d.gather_factors(state, coupled=False)
+    state = d.sweep(state)
+    key, u_ref, v_ref = sweep(key, u.astype(np.float64), v.astype(np.float64),
+                              rows, cols, vals, 3.0, {mode == "async"})
+    u_got, v_got = d.gather_factors(state, coupled=False)
+    for got, want in ((u_got, u_ref), (v_got, v_ref)):
+        gaps.append(float(np.abs(got - want).max() / np.abs(want).max()))
+    if {mode == "async"}:
+        _, v_eval = d.gather_factors(state)
+        assert np.array_equal(v_eval, v), "v_eval is not the v the u half read"
+print(json.dumps(gaps))
+""", devices=4)
+    gaps = json.loads(out.strip().splitlines()[-1])
+    assert len(gaps) == 4 and max(gaps) < 1e-4, gaps
+
+
+def test_lowered_sweep_carries_its_scopes():
+    """The compiled distributed sweep names its layers with the single-chip
+    scopes, and every collective sits under `bpmf.exchange`."""
+    out = run_sub(r"""
+import json, re
+from repro.data import synthetic_lowrank
+from repro.core.distributed import DistributedBPMF
+
+ratings, _, _ = synthetic_lowrank(60, 40, k_true=3, nnz=500, noise=0.3, seed=2)
+found = {}
+for mode in ("async", "allgather"):
+    d = DistributedBPMF(ratings, k=4, mode=mode, width="auto")
+    text = d._sweep.lower(d.init(0), *d._plan_args).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]+)"', text))
+    coll = re.findall(r'= \S+ (?:collective-permute|all-gather|all-reduce)'
+                      r'(?:-start)?\(.*?op_name="([^"]+)"', text)
+    found[mode] = {"scopes": sorted({p for n in names for p in n.split("/")}),
+                   "collectives": coll}
+print(json.dumps(found))
+""", devices=4)
+    found = json.loads(out.strip().splitlines()[-1])
+    for mode, f in found.items():
+        assert {"bpmf.stats", "bpmf.solve", "bpmf.prior", "bpmf.hyper",
+                "bpmf.exchange"} <= set(f["scopes"]), (mode, f["scopes"])
+        assert f["collectives"], mode
+        assert all("bpmf.exchange" in n for n in f["collectives"]), f["collectives"]
