@@ -29,7 +29,8 @@ Two planners share the bucket schema:
   equivalent. `balanced_widths` reads the actual degree histogram and picks
   the width ladder that minimizes the padded workload-model cost (the same
   `cost = fixed + c * n_ratings` model the paper's scheduler balances
-  dynamically), via an exact interval-partition DP over distinct degrees.
+  dynamically), via an exact interval-partition DP over the distinct
+  widths the chip is sent (sublane-aligned from 8 up, `balanced_widths`).
   Item degrees in real rating data are heavily skewed toward the ladder's
   bottom, where a fixed pow2 ladder wastes most of its lanes; fitting the
   ladder to the histogram is what lifts `padding_efficiency` from ~0.3 to
@@ -43,6 +44,9 @@ from typing import Optional, Sequence, Union
 import numpy as np
 
 DEFAULT_WIDTHS = (8, 32, 128, 512)
+
+#: sublane tile of a TPU f32 block: the balanced ladder's alignment
+SUBLANE = 8
 
 #: accepted by every `widths=` parameter that feeds `plan_buckets`
 BALANCED = "balanced"
@@ -99,7 +103,7 @@ def balanced_widths(
     degrees: np.ndarray,
     *,
     max_buckets: int = 8,
-    lane: int = 1,
+    lane: Optional[int] = None,
     max_width: int = 512,
     fixed_cost: float = 1.0,
     per_rating: float = 0.02,
@@ -117,50 +121,59 @@ def balanced_widths(
     and the row count is fixed (one row per unsplit item) — minimizing the
     cost is exactly minimizing padded lanes, with `fixed_cost` only acting
     through the split items' chunk count. The optimal ladder under a bucket
-    budget is an interval partition of the distinct-degree axis, found
+    budget is an interval partition of the distinct-width axis, found
     exactly by DP (O(D^2 * max_buckets) on D <= max_width distinct values —
     microseconds, done once at plan time).
 
     Items with degree > max_width are split across rows of a forced
     `max_width` bucket (chunking keeps their per-row fill near 1, and the
-    DP's remaining buckets fit the small-degree mass). `lane` rounds widths
-    up (lane=8 keeps every bucket MXU-lane aligned for the fused kernel;
-    the default lane=1 maximizes lane efficiency for the einsum engines —
-    `kernels/ops.py` re-pads to 8-lane tiles on the kernel path either way).
+    DP's remaining buckets fit the small-degree mass); with a budget of one
+    bucket that split bucket is the whole ladder.
+
+    Widths are the widths the chip is sent, and the DP charges each row for
+    that width, so the ladder is optimal under the rounding. By default a
+    width of `SUBLANE` (8) or more is a multiple of 8: a gathered
+    `(rows, w, K)` block of such a width is a bitcast of the `(rows * w, K)`
+    gather, where any other width forces a relayout of the whole block on a
+    TPU. Narrower widths stay exact, so the one- and two-rating mass of a
+    sparse profile is not padded up to 8. An explicit `lane` rounds every
+    width up to a multiple of `lane` instead (lane=8 aligns the narrow
+    widths too; lane=1 gives the exact ladder, as the fold-in cache's
+    `FoldInPlanCache.balanced` uses).
     """
     if max_buckets < 1:
         raise ValueError(f"max_buckets must be >= 1, got {max_buckets}")
-    degrees = np.asarray(degrees)
+
+    def sent(w: np.ndarray) -> np.ndarray:
+        step = np.where(w >= SUBLANE, SUBLANE, 1) if lane is None else lane
+        return -(-w // step) * step
+
+    degrees = np.asarray(degrees, np.int64)
     d = degrees[(degrees > 0) & (degrees <= max_width)]
-    oversize = degrees[degrees > max_width]
-
-    def lane_up(w: int) -> int:
-        return -(-int(w) // lane) * lane
-
-    if d.size == 0:
-        return (lane_up(max_width if oversize.size else lane),)
-
-    ds, cs = np.unique(d, return_counts=True)
-    m = len(ds)
-    budget = max_buckets - (1 if oversize.size else 0)
-    budget = max(budget, 1)
-    row_cost = fixed_cost + per_rating * np.array(
-        [lane_up(x) for x in ds], np.float64
+    split = (
+        (int(sent(np.int64(max_width))),) if (degrees > max_width).any() else ()
     )
-    csum = np.concatenate([[0], np.cumsum(cs)])      # csum[i] = count of ds[:i]
+    budget = max_buckets - len(split)
+    if d.size == 0 or budget == 0:
+        return split or (lane or 1,)
+
+    ws, cs = np.unique(sent(d), return_counts=True)
+    m = len(ws)
+    row_cost = fixed_cost + per_rating * ws.astype(np.float64)
+    csum = np.concatenate([[0], np.cumsum(cs)])      # csum[i] = count of ws[:i]
 
     if m <= budget:
         cuts = list(range(1, m + 1))
     else:
-        # f[b, i] = min cost covering ds[:i] with b+1 buckets, the last
-        # bucket ending exactly at ds[i-1] (its width); arg[b, i] = best j
+        # f[b, i] = min cost covering ws[:i] with b+1 buckets, the last
+        # bucket ending exactly at ws[i-1] (its width); arg[b, i] = best j
         inf = np.inf
         f = np.full((budget, m + 1), inf)
         arg = np.zeros((budget, m + 1), np.int64)
-        f[0, 1:] = csum[1:] * row_cost                # one bucket up to ds[i-1]
+        f[0, 1:] = csum[1:] * row_cost                # one bucket up to ws[i-1]
         for b in range(1, budget):
             for i in range(b + 1, m + 1):
-                # last bucket spans ds[j..i-1]; vectorized over j
+                # last bucket spans ws[j..i-1]; vectorized over j
                 j = np.arange(b, i)
                 cand = f[b - 1, j] + (csum[i] - csum[j]) * row_cost[i - 1]
                 best = int(np.argmin(cand))
@@ -173,11 +186,7 @@ def balanced_widths(
             i = int(arg[b, i])
             cuts.append(i)
             b -= 1
-        cuts = sorted(cuts)
-    widths = {lane_up(ds[i - 1]) for i in cuts}
-    if oversize.size:
-        widths.add(lane_up(max_width))
-    return tuple(sorted(widths))
+    return tuple(sorted({int(ws[i - 1]) for i in cuts} | set(split)))
 
 
 def resolve_widths(

@@ -239,10 +239,17 @@ def bucket_stats(
 
                 prec_rows, rhs_rows = kops.masked_syrk(vm, rv)
             else:
+                # Rows that go straight to the solve take the mask on one
+                # operand only (m * m = m: the same products), so on a TPU
+                # the contraction reads the gathered block in the layout the
+                # gather wrote, with no transposed copy of it. Rows that feed
+                # the segment sum keep the symmetric form: there the block is
+                # transposed either way, once for both operands.
+                other = vg if skip_reduce else vm
                 prec_rows = jnp.einsum(
-                    "rwk,rwl->rkl", vm, vm, preferred_element_type=jnp.float32
+                    "rwk,rwl->rkl", vm, other, preferred_element_type=jnp.float32
                 )
-                rhs_rows = jnp.einsum("rwk,rw->rk", vm, rv)
+                rhs_rows = jnp.einsum("rwk,rw->rk", other, rv)
             return reduce(prec_rows, False), reduce(rhs_rows, False)
 
         # stacked draws: one gather + one contraction covering all S draws

@@ -10,12 +10,24 @@ from repro.core import GibbsSampler, plan_buckets
 from repro.core.buckets import (
     BALANCED,
     DEFAULT_WIDTHS,
+    SUBLANE,
     balanced_widths,
     pad_bucket,
     resolve_widths,
 )
-from repro.data import chembl_like, train_test_split
+from repro.data import (
+    chembl_like,
+    movielens_like,
+    synthetic_lowrank,
+    train_test_split,
+)
 from repro.data.sparse import csr_from_coo
+
+
+def _sent(w, lane):
+    """The width the chip is sent under `lane` (None: the sublane rule)."""
+    step = lane or (SUBLANE if w >= SUBLANE else 1)
+    return -(-w // step) * step
 
 
 @settings(max_examples=25, deadline=None)
@@ -23,7 +35,7 @@ from repro.data.sparse import csr_from_coo
     n=st.integers(5, 400),
     zipf_a=st.floats(1.2, 3.0),
     max_buckets=st.integers(1, 10),
-    lane=st.sampled_from([1, 4, 8]),
+    lane=st.sampled_from([None, 1, 4, 8]),
     seed=st.integers(0, 5000),
 )
 def test_balanced_widths_properties(n, zipf_a, max_buckets, lane, seed):
@@ -37,13 +49,13 @@ def test_balanced_widths_properties(n, zipf_a, max_buckets, lane, seed):
     assert len(widths) >= 1
     assert len(widths) <= max_buckets
     assert list(widths) == sorted(set(widths))
-    assert all(w % lane == 0 for w in widths)
+    assert all(w == _sent(w, lane) for w in widths)
     in_range = degrees[(degrees > 0) & (degrees <= 512)]
     if in_range.size:
         assert widths[-1] >= in_range.max() or 512 in widths
     if (degrees > 512).any():
         # oversize mass forces a max-width split bucket
-        assert widths[-1] == -(-512 // lane) * lane
+        assert widths[-1] == _sent(512, lane)
 
 
 def test_balanced_widths_degenerate_inputs():
@@ -51,6 +63,8 @@ def test_balanced_widths_degenerate_inputs():
     assert balanced_widths(np.zeros(10, np.int64)) == (1,)
     # all oversize: only the split bucket
     assert balanced_widths(np.array([9000, 4000]), max_width=512) == (512,)
+    # a budget of one bucket: the split bucket takes the in-range items too
+    assert balanced_widths(np.array([3, 9000]), max_buckets=1) == (512,)
     with pytest.raises(ValueError):
         balanced_widths(np.array([1, 2, 3]), max_buckets=0)
 
@@ -89,6 +103,9 @@ def test_balanced_plan_is_lossless_and_pad_keeps_invariants():
     plan = plan_buckets(indptr, idx, vals, m, n, widths=BALANCED)
     assert plan.nnz == int(np.diff(indptr).sum())
     assert sum(float(b.mask.sum()) for b in plan.buckets) == plan.nnz
+    # each row padded to its bucket's width and no further: the DP's cost
+    assert plan.widths == balanced_widths(np.diff(indptr))
+    assert plan.padded == _sent_slots(np.diff(indptr), plan.widths)
     for b in plan.buckets:
         padded = pad_bucket(b, b.rows + 5, b.n_segments + 3)
         s = padded.seg_ids
@@ -144,3 +161,102 @@ def test_balanced_sweep_matches_pow2_sweep():
     np.testing.assert_allclose(
         np.asarray(st_b.v), np.asarray(st_p.v), rtol=2e-3, atol=2e-3
     )
+
+
+def _degree_profiles():
+    """Degree profiles shaped like the two deployments: ChEMBL's one- and
+    two-rating compounds over a few thousand targets, and MovieLens' ~130
+    ratings a user and ~660 a movie."""
+    chembl, _, _ = chembl_like(scale=0.02, seed=0)
+    ml20m, _, _ = movielens_like(scale=0.01, seed=0)
+    out = {}
+    for name, r in (("chembl", chembl), ("ml20m", ml20m)):
+        out[f"{name}-users"] = np.bincount(r.rows, minlength=r.shape[0])
+        out[f"{name}-items"] = np.bincount(r.cols, minlength=r.shape[1])
+    return out
+
+
+PROFILES = ("chembl-users", "chembl-items", "ml20m-users", "ml20m-items")
+
+
+@pytest.fixture(scope="module")
+def profiles():
+    return _degree_profiles()
+
+
+def _sent_slots(degrees, widths):
+    """Slots the plan sends for `degrees` under `widths`: each item's row
+    width, times its row count where the widest bucket splits it."""
+    w = np.asarray(widths)
+    d = degrees[degrees > 0]
+    fit = w[np.clip(np.searchsorted(w, d), 0, len(w) - 1)]
+    return int((np.maximum(1, -(-d // fit)) * fit).sum())
+
+
+@pytest.mark.parametrize("profile", PROFILES)
+def test_default_ladder_aligns_wide_widths_only(profiles, profile):
+    """Every width of 8 or more is a multiple of the sublane tile; narrower
+    widths are sent exact (each is a degree of the profile); lane=8 still
+    aligns the narrow ones too."""
+    degrees = profiles[profile]
+    exact = balanced_widths(degrees, lane=1)
+    assert any(w % SUBLANE for w in exact if w >= SUBLANE)  # rule matters
+    widths = balanced_widths(degrees)
+    assert all(w % SUBLANE == 0 for w in widths if w >= SUBLANE), widths
+    assert set(w for w in widths if w < SUBLANE) <= set(degrees.tolist())
+    assert all(w % 8 == 0 for w in balanced_widths(degrees, lane=8))
+
+
+@pytest.mark.parametrize("profile", PROFILES)
+def test_default_ladder_is_optimal_under_rounding(profiles, profile):
+    """The DP charges each row the width the chip is sent, so its ladder
+    sends the fewest slots of any aligned ladder within the budget (checked
+    exhaustively for three buckets), and never more than the exact ladder
+    rounded up after the fact."""
+    from itertools import combinations
+
+    degrees = profiles[profile]
+    widths = balanced_widths(degrees, max_buckets=3)
+    in_range = degrees[(degrees > 0) & (degrees <= 512)]
+    cands = sorted({_sent(int(x), None) for x in in_range})
+    split = {512} if (degrees > 512).any() else set()
+    budget = 3 - len(split)
+    best = min(
+        _sent_slots(degrees, sorted(set(c) | {cands[-1]} | split))
+        for n in range(budget)
+        for c in combinations(cands[:-1], n)
+    )
+    assert _sent_slots(degrees, widths) == best
+
+    rounded = sorted({_sent(w, None) for w in balanced_widths(degrees, lane=1)})
+    assert _sent_slots(degrees, balanced_widths(degrees)) <= _sent_slots(
+        degrees, rounded
+    )
+
+
+@pytest.fixture(scope="module")
+def small_data():
+    ratings, _, _ = synthetic_lowrank(200, 150, k_true=6, nnz=6000,
+                                      noise=0.3, seed=2)
+    return train_test_split(ratings, 0.1, seed=3)
+
+
+def test_aligned_ladder_sweep_matches_exact_ladder(small_data):
+    """The pad slots the sublane rule adds carry index 0, value 0 and mask
+    0: two sweeps on the aligned ladder match two on the explicit exact
+    ladder to fp32 summation order."""
+    train, test = small_data
+    degrees = np.bincount(train.rows, minlength=train.shape[0])
+    exact = balanced_widths(degrees, lane=1)
+    assert any(w % SUBLANE for w in exact if w >= SUBLANE)
+    s_al = GibbsSampler(train, test, k=16, alpha=10.0, widths=BALANCED)
+    s_ex = GibbsSampler(train, test, k=16, alpha=10.0, widths=exact)
+    assert s_al.user_plan_host.widths == balanced_widths(degrees)
+    assert s_al.user_plan_host.padded > s_ex.user_plan_host.padded
+    st_a, st_e = s_al.init(0), s_ex.init(0)
+    for _ in range(2):
+        st_a, st_e = s_al.sweep(st_a), s_ex.sweep(st_e)
+    np.testing.assert_allclose(np.asarray(st_a.u), np.asarray(st_e.u),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(st_a.v), np.asarray(st_e.v),
+                               rtol=1e-5, atol=1e-5)

@@ -7,13 +7,16 @@ A kernel Mosaic refuses (an op it cannot lower, an unaligned slice, too
 much VMEM) fails here at no chip time. The topology is described inside a
 fixture, never at import: only one process may load the TPU library.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.gibbs import DeviceBucket, bucket_stats
 from repro.kernels.bpmf_gather_syrk import LANES, gather_syrk_pallas
 from repro.kernels.bpmf_syrk import masked_syrk_pallas
 from repro.kernels.bpmf_topn import topn_scores_pallas
@@ -102,3 +105,42 @@ def test_masked_syrk_compiles_for_v5e(one_chip):
         ((4096, 128, K), jnp.float32), ((4096, 128), jnp.float32),
     )
     assert "tpu_custom_call" in text
+
+
+def _block_moves(text: str, n_elements: int) -> set[str]:
+    """Kinds of the float32 `reshape`s and `copy`s of `n_elements` outside
+    fused computations: each moves the whole block through HBM on a TPU (a
+    reshape that only reinterprets the layout compiles to a `bitcast`)."""
+    fused = set(re.findall(r"fusion\(.*?calls=(%[\w.\-]+)", text))
+    found, skip = set(), False
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            skip = line.split(" ")[0] in fused
+            continue
+        m = re.search(r"= f32\[([\d,]+)\]\S* (reshape|copy)\(", line)
+        if not skip and m and \
+                math.prod(map(int, m.group(1).split(","))) == n_elements:
+            found.add(m.group(2))
+    return found
+
+
+@pytest.mark.parametrize("w,moves", [(78, {"reshape", "copy"}), (80, set())])
+def test_bucket_stats_gather_layout_for_v5e(one_chip, w, moves):
+    """The training statistics at an ML-20M user chunk: 8,192 rows gathered
+    from the (27,278, K) movie table, every row its own segment. At a width
+    that is a multiple of the sublane tile the `(C * w, K)` gather is
+    reinterpreted as `(C, w, K)` and both contractions read it as it lies;
+    at any other width the compiler relays the whole block out first, which
+    is why the balanced ladder sends widths of 8 and more aligned."""
+    c, n = 8192, 27_278
+
+    def stats(table, idx, val, msk):
+        seg = jnp.arange(c, dtype=jnp.int32)
+        bucket = DeviceBucket(w, idx, val, msk, seg, c, seg, True)
+        return bucket_stats(table, bucket)
+
+    text = _compile_text(
+        one_chip, jax.jit(stats), ((n, K), jnp.float32),
+        ((c, w), jnp.int32), ((c, w), jnp.float32), ((c, w), jnp.float32),
+    )
+    assert _block_moves(text, c * w * K) == moves
