@@ -4,7 +4,7 @@
   assembled the sweep state without explicit shardings, so the state the
   first jitted sweep *returned* carried different layouts than the state
   `init()` produced — and the second sweep silently recompiled, putting
-  XLA compile time inside fig5's timed window.  The pass finds the state
+  XLA compile time inside a timed window.  The pass finds the state
   types that flow through ``shard_map`` (constructor calls returned by the
   mapped function), then flags any field of such a constructor inside an
   ``init*`` function whose value is not layout-pinned: accepted forms are

@@ -1,5 +1,4 @@
 from repro.checkpoint.store import CheckpointStore
-from repro.checkpoint.elastic import restore_resharded
 from repro.checkpoint.samples import (
     SAMPLE_KEYS,
     RetainedSample,
@@ -9,7 +8,6 @@ from repro.checkpoint.samples import (
 
 __all__ = [
     "CheckpointStore",
-    "restore_resharded",
     "SAMPLE_KEYS",
     "RetainedSample",
     "SampleStore",

@@ -3,7 +3,7 @@
 Layout:   <root>/step_<N>/manifest.json + leaf_<i>.npy
 Atomicity: written into step_<N>.tmp, fsync'd, then os.rename — a reader
 never observes a partial checkpoint, and a crash mid-save leaves the previous
-checkpoint intact (the fault-tolerance contract runtime/trainer.py relies on).
+checkpoint intact.
 Async mode hands the host-side write to a worker thread so the train loop
 only blocks for the device->host copy.
 """
@@ -130,24 +130,14 @@ class CheckpointStore:
         assert len(out) == len(manifest["leaves"])
         return out
 
-    def restore(self, like: Any, step: int | None = None, shardings: Any = None) -> Any:
-        """Restore into the structure of `like` (shapes validated).
-
-        `shardings`: optional pytree of jax.sharding.Sharding — enables
-        restoring onto a different mesh (see checkpoint/elastic.py).
-        """
+    def restore(self, like: Any, step: int | None = None) -> Any:
+        """Restore into the structure of `like` (shapes validated)."""
         raw = self.read_arrays(step)
         leaves, treedef = jax.tree_util.tree_flatten(like)
         assert len(leaves) == len(raw), (len(leaves), len(raw))
-        shard_leaves = (
-            jax.tree_util.tree_flatten(shardings)[0] if shardings is not None else None
-        )
         out = []
-        for i, (leaf, (path, arr)) in enumerate(zip(leaves, raw.items())):
+        for leaf, (path, arr) in zip(leaves, raw.items()):
             expected = tuple(getattr(leaf, "shape", arr.shape))
             assert tuple(arr.shape) == expected, (path, arr.shape, expected)
-            if shard_leaves is not None:
-                out.append(jax.device_put(arr, shard_leaves[i]))
-            else:
-                out.append(jax.device_put(arr))
+            out.append(jax.device_put(arr))
         return jax.tree_util.tree_unflatten(treedef, out)

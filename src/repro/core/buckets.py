@@ -34,7 +34,7 @@ Two planners share the bucket schema:
   Item degrees in real rating data are heavily skewed toward the ladder's
   bottom, where a fixed pow2 ladder wastes most of its lanes; fitting the
   ladder to the histogram is what lifts `padding_efficiency` from ~0.3 to
-  >0.7 on the ChEMBL-like benchmark profile (`benchmarks/fig4_multicore.py`).
+  >0.7 on the ChEMBL-like degree profile (`tests/test_balanced_plan.py`).
 """
 from __future__ import annotations
 

@@ -493,7 +493,7 @@ class DistributedBPMF:
         # replicate the small leaves explicitly: the sweep's outputs carry
         # these shardings, so an init state laid out any other way makes the
         # SECOND sweep recompile — the whole first-sweeps timing window used
-        # to be compile time (the fig5 "efficiency plateau" artifact)
+        # to be compile time
         rep = NamedSharding(self.mesh, P())
         u = 0.1 * jax.random.normal(ku, (p, self.u_part.n_loc, self.k), jnp.float32)
         v = 0.1 * jax.random.normal(kv, (p, self.v_part.n_loc, self.k), jnp.float32)

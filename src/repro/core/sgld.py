@@ -64,7 +64,6 @@ from repro.core.distributed import (
 from repro.core.gibbs import DeviceBucket, GibbsSampler, factor_stats
 from repro.core.hyper import HyperParams, NWPrior, sample_normal_wishart
 from repro.data.sparse import SparseRatings
-from repro.optim.schedule import sgld_step_schedule
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -73,6 +72,24 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 # shared numerics (single-host and distributed phases both route through
 # these; the exactness unit tests pin them against dense numpy)
 # ---------------------------------------------------------------------------
+
+def sgld_step_schedule(
+    step, *, peak: float, decay: float = 0.33, t0: float = 200.0,
+    floor: float = 0.0,
+):
+    """Polynomial SGLD step-size decay: eps_t = peak * (t0 / (t0 + t))^decay.
+
+    The Welling & Teh (2011) a(b+t)^-gamma family, reparameterized so
+    `peak` IS eps_0 (no coupled a/b algebra when tuning). `decay` < 1
+    keeps the step sum divergent (the chain keeps exploring) while the
+    discretization bias shrinks; `floor` optionally pins a terminal step
+    size for infinite-horizon serving runs where a fully decayed chain
+    would stop mixing.
+    """
+    step = jnp.asarray(step, jnp.float32)
+    eps = peak * (t0 / (t0 + step)) ** decay
+    return jnp.maximum(eps, floor)
+
 
 def row_grads(factors, counterpart, idx, val, msk, items):
     """Per-row likelihood gradient contributions for the row's owning entity.

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels for the BPMF hot spots and attention.
+"""Pallas TPU kernels for the BPMF hot spots.
 
 The paper optimizes the per-item update (outer-product accumulation + a
 Cholesky-based solve, Sec 3.1); these are the corresponding TPU kernels:
@@ -8,7 +8,6 @@ Cholesky-based solve, Sec 3.1); these are the corresponding TPU kernels:
                       (halves the update sweep's dominant traffic)
   chol_solve.py       fused batched Cholesky factor + solve + sample
   bpmf_topn.py        tiled U @ V^T scoring + streaming top-k (BPMF serving)
-  flash_attention.py  tiled online-softmax attention (LM serving/training)
 
 Each kernel ships three layers:
   <name>.py  -- pl.pallas_call + explicit BlockSpec VMEM tiling

@@ -11,7 +11,6 @@ import jax.numpy as jnp
 
 from repro.kernels.bpmf_syrk import masked_syrk_pallas
 from repro.kernels.chol_solve import chol_solve_sample_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
 
 
 def _on_tpu() -> bool:
@@ -81,32 +80,6 @@ def chol_solve_sample(prec: jax.Array, rhs: jax.Array, z: jax.Array,
         interpret=interpret,
     )
     return out.reshape(rhs.shape)
-
-
-def flash_attention(
-    q: jax.Array, k: jax.Array, v: jax.Array,
-    *, causal: bool = True, window: int = 0, softcap: float = 0.0,
-    scale: float | None = None, interpret: bool | None = None,
-):
-    """(BH, S, D) flash attention; pads S to tile multiples, masks the pad."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    bh, sq, d = q.shape
-    sk = k.shape[1]
-    bq = min(128, max(16, sq))
-    bk = min(128, max(16, sk))
-    q_p = _pad_to(q, 1, bq)
-    k_p = _pad_to(k, 1, bk)
-    v_p = _pad_to(v, 1, bk)
-    # padded KV columns are masked inside the kernel only by causal/window;
-    # rely on causal (qpos < padded kpos) for the tail. For non-causal use,
-    # pad K with -inf-producing zeros is insufficient -> explicitly guard:
-    if not causal and k_p.shape[1] != sk:
-        raise ValueError("non-causal flash path requires S_k % block == 0")
-    out = flash_attention_pallas(
-        q_p, k_p, v_p, causal=causal, window=window, softcap=softcap,
-        scale=scale, block_q=bq, block_k=bk, interpret=interpret,
-    )
-    return out[:, :sq]
 
 
 def topn_scores(u: jax.Array, v: jax.Array, topk: int,

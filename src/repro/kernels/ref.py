@@ -35,32 +35,3 @@ def topn_scores_ref(
         preferred_element_type=jnp.float32,
     )
     return jax.lax.top_k(scores, topk)
-
-
-def flash_attention_ref(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    *,
-    causal: bool = True,
-    window: int = 0,
-    softcap: float = 0.0,
-    scale: float | None = None,
-) -> jax.Array:
-    """q,k,v: (BH, S, D). Direct softmax attention in f32."""
-    bh, sq, d = q.shape
-    sk = k.shape[1]
-    scale = scale if scale is not None else 1.0 / jnp.sqrt(d).astype(jnp.float32)
-    s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
-    if softcap > 0:
-        s = jnp.tanh(s / softcap) * softcap
-    qpos = jnp.arange(sq)[:, None]
-    kpos = jnp.arange(sk)[None, :]
-    mask = jnp.ones((sq, sk), bool)
-    if causal:
-        mask &= qpos >= kpos
-    if window > 0:
-        mask &= (qpos - kpos) < window
-    s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bqk,bkd->bqd", p, v.astype(jnp.float32)).astype(q.dtype)

@@ -1,9 +1,9 @@
 """HLO-text cost model with loop-trip-count multiplication.
 
 XLA's `compiled.cost_analysis()` counts every while-loop body ONCE — for a
-scan-over-layers transformer that understates FLOPs by ~n_layers and hides
-per-layer collectives entirely. This module walks the optimized
-(post-SPMD-partitioning) HLO text instead:
+sweep that scans over solve chunks or ring steps that understates FLOPs by
+the trip count and hides per-step collectives entirely. This module walks
+the optimized (post-SPMD-partitioning) HLO text instead:
 
   - dot flops = 2 * result_elems * contracted_elems, multiplied through the
     call graph (while bodies x known_trip_count from backend_config, fusions,
@@ -17,10 +17,9 @@ per-layer collectives entirely. This module walks the optimized
 Shapes in the partitioned module are per-device shards, so all totals are
 per-device; the roofline divides by per-chip peaks directly.
 
-Known approximations (documented in EXPERIMENTS.md): elementwise /
-transcendental flops are ignored (dot-dominated workloads); conditional
-branches are summed; custom-call flops (LAPACK cholesky etc. on the CPU
-backend) are ignored.
+Known approximations: elementwise / transcendental flops are ignored
+(dot-dominated workloads); conditional branches are summed; custom-call
+flops (LAPACK cholesky etc. on the CPU backend) are ignored.
 """
 from __future__ import annotations
 
@@ -205,11 +204,11 @@ class HloCostModel:
     def _fusion_bytes(self, comp: str, op: _Op, called: str | None) -> int:
         """Fusion-boundary traffic with slice-awareness.
 
-        A fusion that merely dynamic-slices a big operand (per-layer weight
-        slices out of the scanned stack, one layer's KV out of the stacked
-        cache) reads only the slice, not the buffer. For each operand whose
-        every consumer inside the fused computation is a dynamic-slice /
-        slice / gather, charge the sliced result size instead.
+        A fusion that merely dynamic-slices a big operand (one chunk out of
+        a scanned stack) reads only the slice, not the buffer. For each
+        operand whose every consumer inside the fused computation is a
+        dynamic-slice / slice / gather, charge the sliced result size
+        instead.
         """
         result = _all_shape_bytes(op.result_seg)
         opnames = _NAME_RE.findall(op.operand_seg)

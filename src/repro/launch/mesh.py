@@ -1,30 +1,15 @@
-"""Production mesh construction.
+"""Device placement for the BPMF launchers: the serving tier's host
+devices, a small mesh over the local devices, and the chip's published peaks.
 
-A function (not a module-level constant) so importing this module never
-touches jax device state. The production target is a TPU v5e-class pod of
-16x16 = 256 chips; the multi-pod mesh prepends a 2-wide "pod" axis
-(2 x 256 = 512 chips) whose links are the slow inter-pod fabric.
+The device lists and the mesh are built by functions, not module-level
+constants, so importing this module never touches jax device state.
 """
 from __future__ import annotations
 
-import math
 from typing import NamedTuple
 
 import numpy as np
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    need = math.prod(shape)
-    devices = jax.devices()
-    if len(devices) < need:
-        raise RuntimeError(
-            f"mesh {shape} needs {need} devices, have {len(devices)} — "
-            "run under XLA_FLAGS=--xla_force_host_platform_device_count=512"
-        )
-    return jax.make_mesh(shape, axes, devices=devices[:need])
 
 
 def serving_host_devices(*, mesh=None, n_hosts: int | None = None) -> list:
@@ -85,7 +70,7 @@ CHIP_PEAKS = {
     "TPU v5 lite": ChipPeaks(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9),
 }
 
-#: the production target the dry-runs model (launch/dryrun.py, bpmf_dryrun.py)
+#: the production target the dry-run models (launch/bpmf_dryrun.py)
 TARGET_KIND = "TPU v5 lite"
 
 

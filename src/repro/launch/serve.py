@@ -1,16 +1,9 @@
-"""Serving launcher: LM decode serving and BPMF recommendation serving.
+"""BPMF serving launcher: posterior-predictive top-N from retained draws.
 
-LM mode (batched prefill + decode for any architecture):
-
-    PYTHONPATH=src python -m repro.launch.serve --arch gemma2-2b --reduced \
-        --batch 4 --prompt-len 64 --max-new 32
-
-BPMF mode (posterior-predictive top-N from retained Gibbs samples):
-
-    PYTHONPATH=src python -m repro.launch.serve --bpmf --samples /path/to/dir \
+    PYTHONPATH=src python -m repro.launch.serve --samples /path/to/dir \
         --requests 256 --max-batch 32 --topk 10
 
-BPMF serving drives the request-batching frontend (repro.serve): requests
+Serving drives the request-batching frontend (repro.serve): requests
 are micro-batched, scored by the Pallas streaming top-k kernel against the
 item-factor cache (keyed by sample epoch, sharded over the host mesh), and
 the run reports queries/sec plus p50/p99 latency. Without --samples it
@@ -19,7 +12,7 @@ trains a small synthetic model first so the command works standalone.
 Co-train mode (train-while-serve, the paper's async overlap applied to the
 train -> serve hand-off):
 
-    PYTHONPATH=src python -m repro.launch.serve --bpmf --co-train \
+    PYTHONPATH=src python -m repro.launch.serve --co-train \
         --sweeps 24 --topk 10
 
 runs the GibbsSampler and the RecommendFrontend in one process, connected
@@ -28,11 +21,11 @@ pushed to the live frontend (no disk poll), which swaps its ensemble
 atomically — reusing the compiled top-N kernel whenever (S, N, K) shapes
 are unchanged — while request traffic keeps flowing. Reports publish
 -> first-fresh-recommendation latency alongside the usual qps numbers.
-The same driver backs `python -m repro.launch.train --bpmf --co-serve`.
+The same driver backs `python -m repro.launch.train --co-serve`.
 
 Multi-host tier mode (the pod-scale scatter/gather layer, simulated):
 
-    PYTHONPATH=src python -m repro.launch.serve --bpmf --hosts 2 \
+    PYTHONPATH=src python -m repro.launch.serve --hosts 2 \
         --requests 256 --topk 10
 
 simulates N serving hosts without hardware: the process re-execs itself
@@ -58,17 +51,6 @@ import time
 
 import numpy as np
 import jax
-import jax.numpy as jnp
-
-from repro.configs import get_config, reduced as reduce_cfg
-from repro.models import build_model
-
-
-def build_serving(cfg, max_new: int):
-    model = build_model(cfg)
-    prefill = jax.jit(lambda p, b: model.prefill_fn(p, b, headroom=max_new + 8))
-    decode = jax.jit(model.decode_fn)
-    return model, prefill, decode
 
 
 def train_demo_samples(root: str, *, seed: int = 0) -> "SparseRatings":
@@ -447,14 +429,6 @@ def bpmf_main(args) -> None:
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="gemma2-2b")
-    ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=64)
-    ap.add_argument("--max-new", type=int, default=32)
-    ap.add_argument("--temperature", type=float, default=0.0)
-    ap.add_argument("--bpmf", action="store_true",
-                    help="serve BPMF recommendations instead of an LM")
     ap.add_argument("--samples", default=None,
                     help="SampleStore directory of retained Gibbs draws")
     ap.add_argument("--topk", type=int, default=10)
@@ -482,7 +456,7 @@ def main():
 
     enable_compile_cache()
 
-    if args.bpmf and args.hosts > 0:
+    if args.hosts > 0:
         _ensure_host_devices(args.hosts)
         run_cluster(
             hosts=args.hosts, replicas=args.replicas, samples=args.samples,
@@ -490,58 +464,7 @@ def main():
             max_batch=min(args.max_batch, 8), publishes=args.publishes,
         )
         return
-    if args.bpmf:
-        bpmf_main(args)
-        return
-
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = reduce_cfg(cfg)
-    model, prefill, decode = build_serving(cfg, args.max_new)
-    params = model.init(jax.random.PRNGKey(0))
-
-    key, k_tok, k_frames, k_patch = jax.random.split(jax.random.PRNGKey(1), 4)
-    batch = {"tokens": jax.random.randint(
-        k_tok, (args.batch, args.prompt_len), 0, cfg.vocab_size, jnp.int32)}
-    if cfg.family == "audio":
-        batch["frames"] = 0.1 * jax.random.normal(
-            k_frames, (args.batch, cfg.encoder_seq, cfg.d_model), jnp.float32)
-    if cfg.family == "vlm":
-        batch["patch_embeds"] = 0.1 * jax.random.normal(
-            k_patch, (args.batch, cfg.n_patches, cfg.d_model), jnp.float32)
-        s_total = cfg.n_patches + args.prompt_len
-        batch["positions"] = jnp.broadcast_to(
-            jnp.arange(s_total, dtype=jnp.int32), (args.batch, 3, s_total))
-
-    t0 = time.time()
-    out = prefill(params, batch)
-    jax.block_until_ready(out["logits"])
-    t_prefill = time.time() - t0
-
-    cache = out["cache"]
-    tok = jnp.argmax(out["logits"], -1)[:, None]
-    pos0 = (cfg.n_patches if cfg.family == "vlm" else 0) + args.prompt_len
-    toks = [tok]
-    t0 = time.time()
-    for t in range(args.max_new - 1):
-        dbatch = {"tokens": tok}
-        if cfg.family == "vlm":
-            dbatch["positions"] = jnp.full((args.batch, 3, 1), pos0 + t, jnp.int32)
-        cache, logits = decode(params, cache, dbatch)
-        if args.temperature > 0:
-            key, sub = jax.random.split(key)
-            tok = jax.random.categorical(sub, logits / args.temperature)[:, None]
-        else:
-            tok = jnp.argmax(logits, -1)[:, None]
-        toks.append(tok)
-    jax.block_until_ready(tok)
-    t_decode = time.time() - t0
-    gen = np.asarray(jnp.concatenate(toks, axis=1))
-
-    n_tok = args.batch * (args.max_new - 1)
-    print(f"arch={cfg.name} batch={args.batch} prompt={args.prompt_len}")
-    print(f"prefill: {t_prefill*1e3:.0f} ms   decode: {n_tok/max(t_decode,1e-9):,.0f} tok/s")
-    print("sample:", gen[0][:16], "...")
+    bpmf_main(args)
 
 
 if __name__ == "__main__":

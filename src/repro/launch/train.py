@@ -1,9 +1,6 @@
-"""Train-step construction: loss + grad + AdamW, with mesh-aware shardings.
+"""BPMF training launcher. Plain training retains post-burn-in draws durably:
 
-Also the BPMF training launcher. Plain training retains post-burn-in draws
-durably:
-
-    PYTHONPATH=src python -m repro.launch.train --bpmf --samples samples/ \
+    PYTHONPATH=src python -m repro.launch.train --samples samples/ \
         --sweeps 24 --k 16
 
 and --co-serve additionally runs a live RecommendFrontend in the same
@@ -12,92 +9,15 @@ process, fed by the asynchronous sample-publication channel
 while the next sweep runs, the overlap the paper makes between computation
 and communication (Sec 4), applied to the train -> serve hand-off:
 
-    PYTHONPATH=src python -m repro.launch.train --bpmf --co-serve --sweeps 24
+    PYTHONPATH=src python -m repro.launch.train --co-serve --sweeps 24
 
-The co-serve path shares its driver with `repro.launch.serve --bpmf
---co-train` (the two entry points are the trainer's and the server's view
-of the same overlapped process).
+The co-serve path shares its driver with `repro.launch.serve --co-train`
+(the two entry points are the trainer's and the server's view of the same
+overlapped process).
 """
 from __future__ import annotations
 
-from typing import Any, NamedTuple
 
-import jax
-import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro.models import build_model, param_pspecs
-from repro.models.layers import ModelConfig
-from repro.optim import AdamWConfig, adamw_init, adamw_update, cosine_schedule
-from repro.optim.adamw import AdamWState
-
-
-class TrainState(NamedTuple):
-    params: Any
-    opt: AdamWState
-    step: jax.Array
-
-
-def init_train_state(cfg: ModelConfig, key: jax.Array, opt_cfg: AdamWConfig) -> TrainState:
-    model = build_model(cfg)
-    params = model.init(key)
-    return TrainState(
-        params=params, opt=adamw_init(params, opt_cfg), step=jnp.zeros((), jnp.int32)
-    )
-
-
-def train_state_shapes(cfg: ModelConfig, opt_cfg: AdamWConfig):
-    """ShapeDtypeStructs of the train state — no allocation (dry-run path)."""
-    return jax.eval_shape(
-        lambda: init_train_state(cfg, jax.random.PRNGKey(0), opt_cfg)
-    )
-
-
-def train_state_pspecs(cfg: ModelConfig, state_shapes: TrainState, mesh) -> TrainState:
-    return TrainState(
-        params=param_pspecs(cfg, state_shapes.params, mesh),
-        opt=AdamWState(
-            m=param_pspecs(cfg, state_shapes.opt.m, mesh),
-            v=param_pspecs(cfg, state_shapes.opt.v, mesh),
-            step=P(),
-        ),
-        step=P(),
-    )
-
-
-def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig, *, total_steps: int = 100_000):
-    model = build_model(cfg)
-
-    def train_step(state: TrainState, batch: dict) -> tuple[TrainState, dict]:
-        def loss_of(p):
-            return model.loss_fn(p, batch)
-
-        (loss, metrics), grads = jax.value_and_grad(loss_of, has_aux=True)(state.params)
-        lr = cosine_schedule(
-            state.step, peak_lr=opt_cfg.lr, warmup_steps=min(2000, total_steps // 10),
-            total_steps=total_steps,
-        )
-        new_params, new_opt, om = adamw_update(grads, state.opt, state.params, opt_cfg, lr=lr)
-        metrics = dict(metrics)
-        metrics.update(om)
-        metrics["loss"] = loss
-        metrics["lr"] = lr
-        return TrainState(params=new_params, opt=new_opt, step=state.step + 1), metrics
-
-    return train_step
-
-
-def shardings_of(pspecs: Any, mesh) -> Any:
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s),
-        pspecs,
-        is_leaf=lambda x: isinstance(x, P),
-    )
-
-
-# ---------------------------------------------------------------------------
-# BPMF training CLI (train -> retain; optionally train-while-serve)
-# ---------------------------------------------------------------------------
 def bpmf_train_main(args) -> None:
     if args.co_serve:
         from repro.launch.serve import run_train_and_serve
@@ -160,16 +80,13 @@ def bpmf_train_main(args) -> None:
                         thin=args.thin, verbose=True)
     print(f"test rmse {sampler.rmse(state):.4f}; retained "
           f"{len(store.steps())} draws; serve them with: "
-          f"python -m repro.launch.serve --bpmf --samples {root}")
+          f"python -m repro.launch.serve --samples {root}")
 
 
 def main():
     import argparse
 
     ap = argparse.ArgumentParser()
-    ap.add_argument("--bpmf", action="store_true",
-                    help="train BPMF (the only CLI mode; LM training is a "
-                         "library — see make_train_step)")
     ap.add_argument("--samples", default=None,
                     help="SampleStore directory for retained draws "
                          "(default: a fresh temp dir)")
@@ -197,7 +114,7 @@ def main():
                          "(per shard when --mode is distributed)")
     ap.add_argument("--step-size", type=float, default=0.3,
                     help="sgld engine: peak Langevin step size (decays "
-                         "polynomially; see optim.schedule.sgld_step_schedule)")
+                         "polynomially; see core.sgld.sgld_step_schedule)")
     ap.add_argument("--thin", type=int, default=1,
                     help="retain every thin-th post-burn-in draw (sgld "
                          "publishes far more often than Gibbs — thin keeps "
@@ -216,8 +133,6 @@ def main():
                     help="serve live recommendations from this process while "
                          "training, via the async publication channel")
     args = ap.parse_args()
-    if not args.bpmf:
-        raise SystemExit("only --bpmf has a CLI; LM training is library-only")
     from repro.launch.compile_cache import enable_compile_cache
 
     enable_compile_cache()

@@ -815,7 +815,7 @@ class ClusterCoordinator:
         """One observability snapshot: committed epoch, per-host health and
         binding state, and per-shard commit-quorum status (who owns it, who
         is serveable, who has staged what). The failure-mode dashboard the
-        chaos suite and benchmarks read."""
+        chaos suite reads."""
         health = self.health.snapshot()
         with self._lock:
             hosts = {}

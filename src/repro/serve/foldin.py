@@ -392,9 +392,7 @@ def fold_in_loop(
 ) -> jax.Array:
     """The original per-retained-draw fold-in: S separate solves in a Python
     loop. Kept as the reference implementation the fused `fold_in` is
-    equivalence-tested against, and as the baseline
-    `benchmarks/foldin_latency.py` measures the fusion speedup from. Not the
-    serving path.
+    equivalence-tested against. Not the serving path.
     """
     _check_fold_in_args(key, ratings, ensemble, sample)
     n_new = ratings.shape[0]

@@ -2,8 +2,7 @@
 
 Serving traffic arrives as single-user requests; the kernel wants batches.
 The frontend queues requests (thread-safe), then `flush()` drains the queue
-in micro-batches of up to `max_batch`, one kernel invocation per batch —
-the same amortisation the LM serving path gets from batched decode steps.
+in micro-batches of up to `max_batch`, one kernel invocation per batch.
 Cold-start requests (raw ratings instead of a user id) ride the same queue:
 each flush folds them in against the current ensemble and scores them
 through the same top-N kernel as trained users.

@@ -136,7 +136,7 @@ class PublicationChannel:
 
     def publish_time(self, step: int) -> float | None:
         """perf_counter timestamp of `step`'s publish, while it is windowed
-        — the freshness clock benchmarks/publish_latency.py reads."""
+        — the clock the publish -> fresh-recommendation latency reads."""
         with self._lock:
             return self._times.get(step)
 

@@ -87,7 +87,7 @@ def _chembl_csr():
 
 def test_chembl_padding_efficiency_gate():
     """The acceptance gate of the planner rewrite: > 0.7 on the chembl-like
-    profile, where the pow2 ladder managed 0.290 (fig4's seed number)."""
+    profile, where the pow2 ladder managed 0.290 (the seed's number)."""
     indptr, idx, vals, m, n = _chembl_csr()
     balanced = plan_buckets(indptr, idx, vals, m, n, widths=BALANCED)
     pow2 = plan_buckets(indptr, idx, vals, m, n, widths=DEFAULT_WIDTHS)

@@ -169,15 +169,3 @@ def test_lpt_partition_properties(n, p, seed):
     np.add.at(loads, part.shard, cost)
     assert loads.max() <= loads.mean() + cost.max() + 1e-9
 
-
-def test_serving_builder_smoke():
-    from repro.launch.serve import build_serving
-    from repro.configs import get_config, reduced
-
-    cfg = reduced(get_config("smollm-360m"))
-    model, prefill, decode = build_serving(cfg, max_new=4)
-    params = model.init(jax.random.PRNGKey(0))
-    out = prefill(params, {"tokens": jnp.ones((2, 8), jnp.int32)})
-    cache, logits = decode(params, out["cache"], {"tokens": jnp.ones((2, 1), jnp.int32)})
-    assert logits.shape == (2, cfg.vocab_size)
-    assert np.isfinite(np.asarray(logits)).all()

@@ -1,18 +1,9 @@
-"""Checkpoint store, fault-tolerant trainer, elastic remesh."""
-import json
-import subprocess
-import sys
-import textwrap
-from pathlib import Path
-
+"""Checkpoint store: round trip, pruning, async save, crash-safe tmp dirs."""
 import numpy as np
 import jax
 import jax.numpy as jnp
-import pytest
 
 from repro.checkpoint import CheckpointStore
-
-SRC = str(Path(__file__).resolve().parents[1] / "src")
 
 
 def tree():
@@ -55,84 +46,3 @@ def test_partial_tmp_dir_is_ignored(tmp_path):
     assert store.latest_step() == 5
     out = store.restore(jax.eval_shape(lambda: tree()))
     assert int(np.asarray(jax.tree.leaves(out)[-1])) == 3
-
-
-def test_trainer_recovers_from_injected_failures(tmp_path):
-    from repro.optim import AdamWConfig
-    from repro.launch.train import init_train_state, make_train_step
-    from repro.runtime import Trainer, TrainerConfig
-    from repro.configs import get_config, reduced
-    from repro.data.tokens import TokenStream
-
-    cfg = reduced(get_config("smollm-360m"))
-    opt = AdamWConfig(lr=1e-3)
-    state = init_train_state(cfg, jax.random.PRNGKey(0), opt)
-    step = jax.jit(make_train_step(cfg, opt, total_steps=100))
-    data = TokenStream(cfg, batch=2, seq=32)
-    tr = Trainer(
-        step, state, data,
-        TrainerConfig(
-            ckpt_dir=str(tmp_path), ckpt_every=5, use_async_ckpt=False,
-            fail_at_steps=(7, 12),
-        ),
-    )
-    out = tr.run(20, log_every=100)
-    assert out["recoveries"] == 2
-    assert out["final_step"] == 20
-    # loss should decrease over the run despite failures
-    losses = out["loss_history"]
-    assert np.mean(losses[-5:]) < np.mean(losses[:5])
-
-
-def test_trainer_resume_from_disk(tmp_path):
-    from repro.optim import AdamWConfig
-    from repro.launch.train import init_train_state, make_train_step
-    from repro.runtime import Trainer, TrainerConfig
-    from repro.configs import get_config, reduced
-    from repro.data.tokens import TokenStream
-
-    cfg = reduced(get_config("smollm-360m"))
-    opt = AdamWConfig(lr=1e-3)
-    step = jax.jit(make_train_step(cfg, opt, total_steps=100))
-    data = TokenStream(cfg, batch=2, seq=32)
-    tcfg = TrainerConfig(ckpt_dir=str(tmp_path), ckpt_every=5, use_async_ckpt=False)
-
-    state = init_train_state(cfg, jax.random.PRNGKey(0), opt)
-    t1 = Trainer(step, state, data, tcfg)
-    t1.run(10, log_every=100)
-
-    # brand-new trainer resumes at step 10 from disk
-    state2 = init_train_state(cfg, jax.random.PRNGKey(0), opt)
-    t2 = Trainer(step, state2, data, tcfg)
-    assert t2.step == 10
-
-
-@pytest.mark.slow
-def test_elastic_remesh_8_to_4_devices():
-    code = f"""
-    import os
-    os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
-    import sys; sys.path.insert(0, {SRC!r})
-    import numpy as np, jax, jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.checkpoint import CheckpointStore, restore_resharded
-
-    mesh8 = jax.make_mesh((4, 2), ("data", "model"), devices=jax.devices()[:8])
-    mesh4 = jax.make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
-    w = jnp.arange(64 * 32, dtype=jnp.float32).reshape(64, 32)
-    tree = {{"w": jax.device_put(w, NamedSharding(mesh8, P("data", "model")))}}
-    store = CheckpointStore("/tmp/elastic_test", keep=1)
-    store.save(3, tree)
-    out = restore_resharded(
-        store, jax.eval_shape(lambda: tree), {{"w": P("data", "model")}}, mesh4, step=3
-    )
-    assert out["w"].sharding.mesh.shape["data"] == 2
-    np.testing.assert_array_equal(np.asarray(out["w"]), np.asarray(w))
-    print("elastic ok")
-    """
-    res = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(code)],
-        capture_output=True, text=True, timeout=300,
-    )
-    assert res.returncode == 0, res.stderr[-3000:]
-    assert "elastic ok" in res.stdout

@@ -1,11 +1,9 @@
-"""Data pipeline: dataset generators + seekable token stream."""
+"""Data pipeline: dataset generators, train/test split, CSR conversion."""
 import numpy as np
 from _hypothesis_compat import given, settings, st
 
-from repro.configs import get_config, reduced
 from repro.data import chembl_like, movielens_like, synthetic_lowrank, train_test_split
 from repro.data.sparse import SparseRatings, csr_from_coo
-from repro.data.tokens import TokenStream
 
 
 def test_chembl_like_shape_and_skew():
@@ -49,25 +47,3 @@ def test_csr_roundtrip(n, m, nnz, seed):
             got.append((i, int(idx[j]), float(v[j])))
     assert sorted(got) == sorted(zip(rows.tolist(), cols.tolist(), vals.astype(float).tolist()))
 
-
-def test_token_stream_deterministic_and_seekable():
-    cfg = reduced(get_config("smollm-360m"))
-    s1 = TokenStream(cfg, batch=4, seq=32, seed=5)
-    s2 = TokenStream(cfg, batch=4, seq=32, seed=5)
-    b_100_a = s1(100)
-    _ = s1(3)  # stream position is irrelevant
-    b_100_b = s2(100)
-    np.testing.assert_array_equal(b_100_a["tokens"], b_100_b["tokens"])
-    # labels are next-token shifted
-    np.testing.assert_array_equal(b_100_a["tokens"][:, 1:], b_100_a["labels"][:, :-1])
-
-
-def test_token_stream_family_extras():
-    for arch in ("whisper-medium", "qwen2-vl-7b"):
-        cfg = reduced(get_config(arch))
-        b = TokenStream(cfg, batch=2, seq=16)(0)
-        if cfg.family == "audio":
-            assert b["frames"].shape == (2, cfg.encoder_seq, cfg.d_model)
-        if cfg.family == "vlm":
-            assert b["patch_embeds"].shape == (2, cfg.n_patches, cfg.d_model)
-            assert b["labels"].shape[1] == cfg.n_patches + 16

@@ -1,4 +1,4 @@
-"""Distributed BPMF + grad compression. Multi-device tests run in
+"""Distributed BPMF. Multi-device tests run in
 subprocesses (jax pins the device count at first init)."""
 import json
 import subprocess
@@ -161,77 +161,6 @@ def test_async_first_sweep_v_bitwise_and_rmse_parity():
     res = json.loads(out.strip().splitlines()[-1])
     assert abs(res["ring"] - res["async"]) < 0.05
     assert res["async"] < 0.7
-
-
-@pytest.mark.slow
-def test_int8_compressed_psum_error_feedback():
-    out = run_sub("""
-    import numpy as np, jax, jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-    from repro.optim.compress import compress_init, compressed_psum, CompressState
-
-    mesh = jax.make_mesh((8,), ("pod",))
-    g_global = np.random.default_rng(0).normal(size=(8, 64, 32)).astype(np.float32)
-
-    def f(g, err):
-        out, st = compressed_psum({"w": g[0]}, CompressState(error={"w": err[0]}), "pod")
-        return out["w"][None], st.error["w"][None]
-
-    m = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                  out_specs=(P("pod"), P("pod")), check_vma=False)
-    errs = np.zeros_like(g_global)
-    # accumulate over rounds: error feedback keeps the running sum unbiased
-    total_true = g_global.sum(0)
-    out, errs2 = jax.jit(m)(jnp.asarray(g_global), jnp.asarray(errs))
-    got = np.asarray(out)[0]
-    rel = np.abs(got - total_true).max() / np.abs(total_true).max()
-    assert rel < 0.05, rel
-    # second round with carried error: residual shrinks the bias
-    out2, _ = jax.jit(m)(jnp.asarray(g_global), errs2)
-    print("ok", rel)
-    """)
-    assert "ok" in out
-
-
-def test_compress_roundtrip_single_device():
-    import jax.numpy as jnp
-    from repro.optim.compress import int8_compress, int8_decompress
-
-    rng = np.random.default_rng(0)
-    g = jnp.asarray(rng.normal(size=(128, 64)), jnp.float32)
-    e = jnp.zeros_like(g)
-    q, scale, new_e = int8_compress(g, e)
-    deq = int8_decompress(q, scale)
-    np.testing.assert_allclose(np.asarray(deq + new_e), np.asarray(g), atol=1e-5)
-    assert np.abs(np.asarray(new_e)).max() <= float(scale) / 2 + 1e-6
-
-
-@pytest.mark.slow
-def test_moe_ep_shard_map_matches_grouped():
-    """The shard_map EP dispatch (§Perf iteration 5) must be numerically
-    faithful to the single-device grouped dispatch."""
-    out = run_sub("""
-    import numpy as np, dataclasses
-    import jax, jax.numpy as jnp
-    from repro.models.layers import ModelConfig, init_moe, moe_block, active_mesh
-
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-    cfg = ModelConfig(name="t", family="moe", n_layers=1, d_model=32, n_heads=4,
-                      n_kv_heads=2, d_ff=0, vocab_size=64, n_experts=8,
-                      n_experts_active=2, moe_d_ff=16, capacity_factor=8.0,
-                      dtype=jnp.float32, param_dtype=jnp.float32)
-    params = init_moe(jax.random.PRNGKey(0), cfg)
-    x = jax.random.normal(jax.random.PRNGKey(1), (4, 64, 32), jnp.float32)
-    o_ref, a_ref = moe_block(params, x, dataclasses.replace(cfg, moe_group_dispatch=True))
-    cfg_ep = dataclasses.replace(cfg, moe_ep_shard_map=True)
-    with mesh, active_mesh(mesh):
-        o_ep, a_ep = jax.jit(lambda p, xx: moe_block(p, xx, cfg_ep))(params, x)
-    np.testing.assert_allclose(np.asarray(o_ref), np.asarray(o_ep), rtol=3e-3, atol=3e-3)
-    # aux: local-mean estimator vs global — close but not identical
-    np.testing.assert_allclose(float(a_ref), float(a_ep), rtol=5e-2)
-    print("ep ok")
-    """)
-    assert "ep ok" in out
 
 
 def test_finish_phase_chunked_solve_matches_whole(monkeypatch):

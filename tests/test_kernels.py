@@ -113,57 +113,6 @@ def test_chol_solve_lane_stats_count_systems_and_tiles():
 
 
 # ---------------------------------------------------------------------------
-# flash attention
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("bh,s,d,window,cap", [
-    (4, 128, 32, 0, 0.0),
-    (2, 256, 64, 64, 0.0),
-    (3, 128, 32, 0, 30.0),
-    (1, 384, 64, 128, 50.0),
-    (2, 200, 32, 0, 0.0),          # non-multiple S -> padding path
-])
-def test_flash_vs_ref(bh, s, d, window, cap):
-    rng = np.random.default_rng(s + d)
-    q = jnp.asarray(rng.normal(size=(bh, s, d)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(bh, s, d)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(bh, s, d)), jnp.float32)
-    o1 = ops.flash_attention(q, k, v, causal=True, window=window, softcap=cap)
-    o2 = ref.flash_attention_ref(q, k, v, causal=True, window=window, softcap=cap)
-    np.testing.assert_allclose(o1, o2, rtol=3e-4, atol=3e-4)
-
-
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-4), (jnp.bfloat16, 3e-2)])
-def test_flash_dtypes(dtype, tol):
-    rng = np.random.default_rng(9)
-    q = jnp.asarray(rng.normal(size=(2, 128, 64)), dtype)
-    k = jnp.asarray(rng.normal(size=(2, 128, 64)), dtype)
-    v = jnp.asarray(rng.normal(size=(2, 128, 64)), dtype)
-    o1 = ops.flash_attention(q, k, v, causal=True)
-    o2 = ref.flash_attention_ref(q, k, v, causal=True)
-    np.testing.assert_allclose(
-        np.asarray(o1, np.float32), np.asarray(o2, np.float32), rtol=tol, atol=tol
-    )
-
-
-def test_flash_matches_model_chunked_attention():
-    """The jnp chunked attention in models/layers.py is the second oracle."""
-    from repro.models.layers import multi_head_attention
-
-    rng = np.random.default_rng(3)
-    b, s, h, hd = 2, 256, 4, 32
-    q = jnp.asarray(rng.normal(size=(b, s, h, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(b, s, h, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(b, s, h, hd)), jnp.float32)
-    o_model = multi_head_attention(q, k, v, causal=True, chunk=64)
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, hd)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, s, hd)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, s, hd)
-    o_kernel = ops.flash_attention(qf, kf, vf, causal=True)
-    o_kernel = o_kernel.reshape(b, h, s, hd).transpose(0, 2, 1, 3)
-    np.testing.assert_allclose(o_model, o_kernel, rtol=5e-4, atol=5e-4)
-
-
-# ---------------------------------------------------------------------------
 # fused gather+syrk+segment-reduce (V stays in HBM; rows gathered in-kernel)
 # ---------------------------------------------------------------------------
 def _sorted_segments(rng, r, n_seg):
