@@ -9,7 +9,9 @@ every user from (R, V); then predict the test points. The per-item update is
     u_i      ~ N(Lambda_i^-1 b_i, Lambda_i^-1)
 
 computed bucket-by-bucket as batched masked syrk (MXU) + batched Cholesky
-sample — full inverses are never formed (paper Sec 3.1). The sufficient
+sample — the per-item systems never form an inverse (paper Sec 3.1); only
+the shared K x K prior factor is inverted, once per half-sweep, so that the
+prior draw of every item is one matmul. The sufficient
 statistics for the *next* hyperparameter draw are fused into the sweep.
 """
 from __future__ import annotations
@@ -482,9 +484,15 @@ def update_factors(
             lam = hyper.lam.astype(dtype)
             lam_mu = (hyper.lam @ hyper.mu).astype(dtype)
             z = jax.random.normal(key, (n_items, k), dtype)
-            # items with no ratings keep the prior: one shared Cholesky factor
-            new = chol_subst_solve(
-                jnp.linalg.cholesky(lam), jnp.broadcast_to(lam_mu, (n_items, k)), z
+            # items with no ratings keep the prior draw mu + L^-T z_i, with
+            # Lambda = L L^T shared by every item: L is inverted once
+            # (K x K) and the draw is one matmul, in rows mu + z @ L^-1
+            l_inv = jax.lax.linalg.triangular_solve(
+                jnp.linalg.cholesky(lam), jnp.eye(k, dtype=dtype),
+                left_side=True, lower=True,
+            )
+            new = hyper.mu.astype(dtype) + jnp.dot(
+                z, l_inv, precision=jax.lax.Precision.HIGHEST
             )
         solver = _sweep_solver(engine)
 

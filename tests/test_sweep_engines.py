@@ -86,6 +86,76 @@ def test_update_factors_engines_match(small_data):
     np.testing.assert_allclose(out["fused"], out["reference"], atol=2e-4, rtol=2e-4)
 
 
+def _spd_hyper(rng, k):
+    """A hyper-prior with a full, well-conditioned precision and a non-zero
+    mean, so the prior draw's shared factor is not the identity."""
+    from repro.core.hyper import HyperParams
+
+    a = rng.normal(size=(k, k)) / np.sqrt(k)
+    lam = (a @ a.T + 0.5 * np.eye(k)).astype(np.float32)
+    mu = rng.normal(size=k).astype(np.float32)
+    return HyperParams(mu=jnp.asarray(mu), lam=jnp.asarray(lam))
+
+
+@pytest.mark.parametrize("side", ["users", "items"])
+@pytest.mark.parametrize("engine", ["einsum", "fused"])
+def test_update_factors_unrated_rows_keep_the_prior_draw(engine, side):
+    """Rows with no ratings get mu + L^-T z_i (Lambda = L L^T) from the same
+    noise rows the sweep draws, checked in float64; rated rows match the
+    reference engine."""
+    from repro.data.sparse import SparseRatings
+
+    rng = np.random.default_rng(5)
+    m, n, k, nnz = 60, 50, 16, 900
+    ratings = SparseRatings(  # users >= 40 and items >= 35 rate nothing
+        rows=rng.integers(0, 40, nnz).astype(np.int32),
+        cols=rng.integers(0, 35, nnz).astype(np.int32),
+        vals=rng.normal(size=nnz).astype(np.float32), shape=(m, n),
+    )
+    s = GibbsSampler(ratings, None, k=k, alpha=8.0, widths=(8, 32))
+    state = s.init(0)
+    hyper = _spd_hyper(rng, k)
+    key = jax.random.PRNGKey(9)
+    if side == "users":
+        args, rated = (state.v, s.user_buckets, m), np.unique(ratings.rows)
+    else:
+        args, rated = (state.u, s.item_buckets, n), np.unique(ratings.cols)
+    n_items = args[2]
+    unrated = np.setdiff1d(np.arange(n_items), rated)
+    assert len(unrated) and len(rated)
+
+    def run(eng):
+        new, _ = update_factors(key, *args, hyper, 8.0, engine=eng)
+        return np.asarray(new)
+
+    got = run(engine)
+    z = np.asarray(jax.random.normal(key, (n_items, k), jnp.float32), np.float64)
+    chol = np.linalg.cholesky(np.asarray(hyper.lam, np.float64))
+    want = np.asarray(hyper.mu, np.float64) + np.linalg.solve(chol.T, z.T).T
+    np.testing.assert_allclose(got[unrated], want[unrated], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(got[rated], run("reference")[rated],
+                               atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("k", [16, 64])
+@pytest.mark.parametrize("n_items", [1, 37, 1000])
+def test_prior_draw_matches_substitution_form(n_items, k):
+    """The prior draw (one K x K inverse and one matmul) against the form it
+    replaced: both substitutions over every row with the shared factor."""
+    hyper = _spd_hyper(np.random.default_rng(n_items + k), k)
+    key = jax.random.PRNGKey(n_items)
+    counterpart = jnp.zeros((1, k), jnp.float32)  # no buckets: no rated rows
+    got, _ = update_factors(key, counterpart, [], n_items, hyper, 8.0,
+                            engine="einsum")
+    z = jax.random.normal(key, (n_items, k), jnp.float32)  # repro-lint: disable=prng-reuse (the sweep's own noise, regenerated)
+    want = chol_subst_solve(
+        jnp.linalg.cholesky(hyper.lam),
+        jnp.broadcast_to(hyper.lam @ hyper.mu, (n_items, k)), z,
+    )
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("engine", ["einsum", "fused"])
 def test_update_factors_row_chunks_match_whole_buckets(small_data, engine,
                                                        monkeypatch):
